@@ -9,7 +9,8 @@ halo stencil) take their offsets as ``firstprivate`` value parameters, so
 one shared function covers every tile and the staged executor batches a
 whole wavefront into a single vmap dispatch.  Sizes are parameters —
 tests use laptop-scale instances; the DES workloads carry the paper's
-§4.2 sizes.
+§4.2 sizes.  ``seed`` makes each app's input data (the default 0 keeps
+the data every test and baseline was written against).
 """
 from __future__ import annotations
 
@@ -31,9 +32,10 @@ def _price(spot, strike, t, rate, vol, call=None, put=None):
 
 
 def black_scholes_app(rt: TaskRuntime, n_options: int = 8192,
-                      task_options: int = 512, verify: bool = True):
+                      task_options: int = 512, verify: bool = True,
+                      seed: int = 0):
     """Independent pricing tasks — embarrassingly parallel (§4.2)."""
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     cols = {
         "spot": rng.uniform(10, 200, n_options).astype(np.float32),
         "strike": rng.uniform(10, 200, n_options).astype(np.float32),
@@ -77,9 +79,9 @@ def _gemm(c, x, y):
 
 
 def matmul_app(rt: TaskRuntime, n: int = 256, tile: int = 64,
-               verify: bool = True):
+               verify: bool = True, seed: int = 0):
     g = n // tile
-    rng = np.random.default_rng(1)
+    rng = np.random.default_rng(seed + 1)
     a = rng.standard_normal((n, n), dtype=np.float32)
     b = rng.standard_normal((n, n), dtype=np.float32)
     with rt.scope():
@@ -106,11 +108,11 @@ def _row_fft(re, im, re_out=None, im_out=None):
 
 
 def fft2d_app(rt: TaskRuntime, n: int = 256, row_block: int = 32,
-              tile: int = 32, verify: bool = True):
+              tile: int = 32, verify: bool = True, seed: int = 0):
     """2-D FFT exactly as the paper structures it: row-FFT tasks on
     32-row blocks, 32x32 tiled transpose tasks, row-FFT tasks again.
     Complex data as separate re/im planes."""
-    rng = np.random.default_rng(2)
+    rng = np.random.default_rng(seed + 2)
     x = (rng.standard_normal((n, n)) +
          1j * rng.standard_normal((n, n))).astype(np.complex64)
 
@@ -168,11 +170,11 @@ def fft2d_app(rt: TaskRuntime, n: int = 256, row_block: int = 32,
 
 # ---------------------------------------------------------------------------
 def jacobi_app(rt: TaskRuntime, n: int = 256, tile: int = 64,
-               iters: int = 4, verify: bool = True):
+               iters: int = 4, verify: bool = True, seed: int = 0):
     """Tiled 5-point Jacobi: each task reads its tile plus the available
     neighbour tiles (one footprint region) and writes its tile — the halo
     dependencies the paper's stencil workloads exhibit."""
-    rng = np.random.default_rng(3)
+    rng = np.random.default_rng(seed + 3)
     x0 = rng.standard_normal((n, n)).astype(np.float32)
     g = n // tile
     with rt.scope():
@@ -221,9 +223,9 @@ def _update(c, x, y):
 
 
 def cholesky_app(rt: TaskRuntime, n: int = 256, tile: int = 64,
-                 verify: bool = True):
+                 verify: bool = True, seed: int = 0):
     g = n // tile
-    rng = np.random.default_rng(4)
+    rng = np.random.default_rng(seed + 4)
     m = rng.standard_normal((n, n)).astype(np.float32)
     spd = m @ m.T + n * np.eye(n, dtype=np.float32)
     with rt.scope():

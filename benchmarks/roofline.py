@@ -20,9 +20,10 @@ import os
 import jax
 
 from repro.configs import SHAPES, get_config
-from repro.core.costmodel import TPUParams
+from repro.core.costmodel import tpu_params
 
-HW = TPUParams()
+# the dry-run cells are compiled for a described TPU v5e
+HW = tpu_params("TPU v5 lite")
 
 
 def model_params(arch: str) -> dict:

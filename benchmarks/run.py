@@ -197,8 +197,10 @@ def app_entries(cfg: dict, report, sim_params=None,
 
 
 # apps in the kernel-backend sweep: every wave is rectangular and
-# homogeneous, so the pallas backend must fuse them all — a fallback
-# here means the eligibility rules or the grouping signature regressed
+# homogeneous, so under the Pallas interpreter (the CPU runs this suite
+# takes) the pallas backend must fuse them all — a fallback here means the
+# eligibility rules or the grouping signature regressed.  On a TPU the
+# compiler refuses Jacobi's stencil, a fallback named "compile_refused"
 KERNEL_SWEEP_APPS = ("matmul", "jacobi")
 
 
@@ -514,6 +516,8 @@ def main(argv=None) -> None:
                          "profile_waves annotations enabled")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,metric,value")
     doc, ok = build_bench(args.suite, skip_roofline=args.skip_roofline,
                           owner_skew=args.owner_skew, trace=args.trace,

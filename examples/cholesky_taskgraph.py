@@ -4,8 +4,10 @@ The right-looking factorization's three kernels are declared once as
 ``@task`` functions; calling them inside the runtime scope spawns tile
 tasks whose footprints overlap heavily.  BDDT dependence analysis
 discovers the DAG (RAW through the panel, WAW on diagonal updates) and
-the staged executor runs it in wavefronts — on TPU the update tasks are
-the Pallas ``tile_update`` kernel.  ``wait_on(A[0, 0])`` demonstrates
+the staged executor runs it in wavefronts.  The update tasks run XLA's
+dot; only under ``RuntimeConfig(kernel_backend="pallas")`` on a TPU does a
+wave of them become one fused Pallas kernel (``core/wavekernel.py``).
+``wait_on(A[0, 0])`` demonstrates
 region-scoped sync: the first diagonal tile is final long before the
 trailing submatrix drains.
 

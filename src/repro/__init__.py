@@ -14,11 +14,7 @@ importable for extension work, but examples, benchmarks and docs only
 use the names re-exported here.
 """
 
-from . import jax_compat as _jax_compat
-
-_jax_compat.install()
-
-from .core import (AccessMode, BlockArray, DEP_MANAGERS, DEP_PUMPS,  # noqa: E402
+from .core import (AccessMode, BlockArray, DEP_MANAGERS, DEP_PUMPS,
                    EXECUTORS, ExecutorKind, DepManagerKind, DepPumpKind,
                    Executor, In, InOut, KERNEL_BACKENDS, KernelBackend,
                    Out, PLACEMENTS, PlacementKind, Region, RuntimeConfig,

@@ -286,9 +286,11 @@ class RuntimeConfig:
       default vmap/shard_map path) or ``"pallas"`` (lower each eligible
       group into one fused ``pl.pallas_call`` whose grid axis is the task
       axis — ``core/wavekernel.py``, the §3.2 on-chip staging analogue).
-      Ineligible groups automatically fall back to the XLA path; the
-      runtime counts them in ``RuntimeStats.kernel_fallbacks`` and tags
-      each decision with a ``kernel_dispatch`` tracker event.  The sim
+      Ineligible groups, and groups whose kernel the TPU compiler
+      refuses, automatically fall back to the XLA path; the runtime
+      counts them in ``RuntimeStats.kernel_fallbacks`` (by reason in
+      ``kernel_fallback_reasons``) and tags each decision with a
+      ``kernel_dispatch`` tracker event.  The sim
       executor uses the same eligibility to predict which waves fuse and
       charges their write-back traffic at on-chip (MPB) cost.
     * ``sim_cost_fn`` — "sim" executor: ``td -> (flops, bytes)``; the
@@ -414,11 +416,15 @@ class RuntimeStats:
     waves: int | None = None
     grouped_dispatches: int | None = None
     # wave-kernel backend (kernel_backend="pallas"): groups fused into one
-    # pallas grid vs groups that took the XLA fallback (both None under
-    # kernel_backend="xla", where the layer is inert).  The sim executor
-    # fills the same fields with its *predicted* fuse/fallback split.
+    # pallas grid vs groups that took the XLA fallback, and the fallbacks
+    # counted by reason ("single_task", "vmem_budget", "compile_refused",
+    # ...; all None under kernel_backend="xla", where the layer is
+    # inert).  The sim executor fills the same fields with its
+    # *predicted* fuse/fallback split (structural reasons only: it never
+    # compiles).
     kernel_dispatches: int | None = None
     kernel_fallbacks: int | None = None
+    kernel_fallback_reasons: dict[str, int] | None = None
     # sharded executor: owner-computes traffic accounting (§4.1-§4.2
     # generalized — cross-home bytes are what the DES charges contention
     # for) plus how many grouped dispatches went through the

@@ -57,20 +57,10 @@ def device_of(x):
 
     Uncommitted arrays (eager results on a single-device platform) have no
     residency obligation — moving them is free in the residency model, so
-    they report None and are never charged as transfers.  Committedness
-    comes from the public ``jax.Array.committed`` property (private
-    ``_committed`` as a fallback for older releases); if neither exists,
-    a single-device array on a multi-device platform is conservatively
-    treated as committed, so mixed-device assembly harmonizes instead of
-    crashing inside ``jnp.block``/``stack``."""
+    they report None and are never charged as transfers."""
     if not isinstance(x, jax.Array):
         return None
-    committed = getattr(x, "committed", None)
-    if committed is None:
-        committed = getattr(x, "_committed", None)
-    if committed is None:
-        committed = len(jax.devices()) > 1
-    if committed:
+    if x.committed:
         devs = x.devices()
         if len(devs) == 1:
             return next(iter(devs))

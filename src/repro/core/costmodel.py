@@ -97,10 +97,13 @@ class SCCParams:
 
 @dataclass(frozen=True)
 class TPUParams:
-    """Target-hardware constants for the roofline (TPU v5e)."""
-    peak_flops_bf16: float = 197e12
-    hbm_bw: float = 819e9
-    ici_link_bw: float = 50e9
+    """Target-hardware peaks for the roofline, per chip.  Look them up by
+    the device's ``device_kind`` with :func:`tpu_params`; never assume a
+    device."""
+    peak_flops_bf16: float
+    hbm_bw: float
+    ici_link_bw: float
+    source: str
 
     def roofline_terms(self, flops: float, hbm_bytes: float,
                        link_bytes: float, chips: int = 1) -> dict:
@@ -109,6 +112,27 @@ class TPUParams:
             "memory_s": hbm_bytes / (chips * self.hbm_bw),
             "collective_s": link_bytes / (chips * self.ici_link_bw),
         }
+
+
+#: published per-chip peaks keyed by ``jax.Device.device_kind``
+TPU_PEAKS: dict[str, TPUParams] = {
+    # 1,600 Gbit/s of chip-to-chip interconnect over four links
+    "TPU v5 lite": TPUParams(
+        peak_flops_bf16=197e12, hbm_bw=819e9, ici_link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI"),
+}
+
+
+def tpu_params(device_kind: str) -> TPUParams:
+    """The peaks of one chip of ``device_kind``; an unknown kind is an
+    error, not a default."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(TPU_PEAKS)}") \
+            from None
 
 
 def master_core_choice() -> int:

@@ -302,11 +302,14 @@ class StagedExecutor(ExecutorBase):
         self.pending: list[TaskDescriptor] = []
         self._vjit: dict[Callable, Callable] = {}
         self._jit: dict[Callable, Callable] = {}
-        self._pjit: dict[tuple, Callable] = {}   # built wave kernels
+        # compiled wave kernels, or the WaveKernelError that refused one
+        # (a refusal is cached too: the same group shape never recompiles)
+        self._pjit: dict[tuple, Callable | wavekernel.WaveKernelError] = {}
         self.waves_run = 0
         self.grouped_dispatches = 0
         self.kernel_dispatches = 0     # groups fused into one pallas grid
         self.kernel_fallbacks = 0      # pallas-requested groups gone XLA
+        self.kernel_fallback_reasons: dict[str, int] = defaultdict(int)
         self._dispatches = 0           # all dispatch events this executor
         self._wave_id = 0              # current wave (event correlation)
         self._last_mode = "jit"        # how the last group dispatched
@@ -452,10 +455,10 @@ class StagedExecutor(ExecutorBase):
 
     def _run_group(self, group: list[TaskDescriptor]) -> None:
         if self.kernel_backend == "pallas":
-            reason = self._try_wave_kernel(group)
-            if reason is None:
+            refusal = self._try_wave_kernel(group)
+            if refusal is None:
                 return                 # fused pallas grid dispatched
-            self._note_kernel_fallback(group, reason)
+            self._note_kernel_fallback(group, *refusal)
         fn = group[0].fn
         if len(group) == 1 or not self.group:
             jfn = self._jitted(fn)
@@ -474,16 +477,19 @@ class StagedExecutor(ExecutorBase):
         self._store_group(group, result)
 
     # -- the pallas wave-kernel backend (kernel_backend="pallas") -------------
-    def _try_wave_kernel(self, group: list[TaskDescriptor]) -> str | None:
+    def _try_wave_kernel(self, group: list[TaskDescriptor]) \
+            -> tuple[str, str] | None:
         """Dispatch the group as one fused pallas grid if it qualifies.
         Returns None on success (results committed), else the fallback
-        reason — the caller then takes the XLA path, which stays the
-        reference oracle for everything the lowering does not cover."""
+        ``(reason, error)`` — the caller then takes the XLA path, which
+        stays the reference oracle for everything the lowering does not
+        cover.  Only building the kernel is guarded: the compiled kernel
+        is called outside any ``try``, so a device fault propagates."""
         if not self.group:
-            return "ungrouped"
+            return "ungrouped", ""
         reason = wavekernel.eligibility(group)
         if reason is not None:
-            return reason
+            return reason, ""
         td = group[0]
         label = td.name or td.fn.__name__
         for t in group:
@@ -491,24 +497,19 @@ class StagedExecutor(ExecutorBase):
         ins = self._stack_group(group)
         key = (td.fn, len(group),
                tuple((tuple(x.shape), str(x.dtype)) for x in ins))
-        try:
-            pfn = self._pjit.get(key)
-            if pfn is None:
-                in_structs = [jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
-                              for x in ins]
-                out_structs = wavekernel.infer_out_structs(
-                    td.fn, in_structs, len(td.outputs), label)
-                pfn = self._pjit[key] = wavekernel.build_wave_kernel(
-                    td.fn, len(group), in_structs, out_structs,
-                    interpret=wavekernel.interpret_mode(), label=label)
-            with suspend_runtime_scope():   # tracing runs fn on this thread
-                result = pfn(*ins)
-        except Exception:
-            # untraceable body, unsupported op under the pallas
-            # interpreter, compiler limits... — every lowering failure
-            # degrades to the XLA path, where a genuine task-body error
-            # resurfaces to the user unchanged
-            return "lowering_failed"
+        pfn = self._pjit.get(key)
+        if pfn is None:
+            try:
+                with suspend_runtime_scope():   # tracing runs fn here
+                    pfn = wavekernel.compile_wave_kernel(
+                        td.fn, ins, len(td.outputs),
+                        interpret=wavekernel.interpret_mode(), label=label)
+            except wavekernel.WaveKernelError as e:
+                pfn = e
+            self._pjit[key] = pfn
+        if isinstance(pfn, wavekernel.WaveKernelError):
+            return pfn.reason, pfn.detail
+        result = pfn(*ins)
         self._last_mode = "pallas"
         self.kernel_dispatches += 1
         if self.obs.enabled:
@@ -519,15 +520,18 @@ class StagedExecutor(ExecutorBase):
         return None
 
     def _note_kernel_fallback(self, group: list[TaskDescriptor],
-                              reason: str) -> None:
-        """Account one pallas-requested group that takes the XLA path."""
+                              reason: str, error: str = "") -> None:
+        """Account one pallas-requested group that takes the XLA path;
+        ``error`` is the compiler's first error line for a refusal."""
         self.kernel_fallbacks += 1
+        self.kernel_fallback_reasons[reason] += 1
         if self.obs.enabled:
             td = group[0]
+            extra = {"error": error} if error else {}
             self.obs.emit("kernel_dispatch", wave=self._wave_id,
                           executor=self.kind,
                           fn=td.name or td.fn.__name__, tasks=len(group),
-                          backend="xla", reason=reason)
+                          backend="xla", reason=reason, **extra)
 
     # -- wave instrumentation -------------------------------------------------
     def _traffic_snapshot(self) -> tuple[int, int, int]:

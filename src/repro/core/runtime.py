@@ -351,6 +351,8 @@ class TaskRuntime:
         if getattr(self._exec, "kernel_backend", "xla") == "pallas":
             s.kernel_dispatches = self._exec.kernel_dispatches
             s.kernel_fallbacks = self._exec.kernel_fallbacks
+            s.kernel_fallback_reasons = dict(
+                self._exec.kernel_fallback_reasons)
         # residency semantics are shared by all five executors: the
         # measured movement comes from the memory layer's recorder (zero
         # under executors that never place tiles on devices)

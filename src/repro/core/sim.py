@@ -208,6 +208,7 @@ class SimExecutor(ExecutorBase):
         self.kernel_backend = kernel_backend
         self.kernel_dispatches = 0
         self.kernel_fallbacks = 0
+        self.kernel_fallback_reasons: dict[str, int] = {}
         self.pending = []
         self.last_result: SimResult | None = None
         # fragments compose sequentially (each sync point serializes the
@@ -251,11 +252,14 @@ class SimExecutor(ExecutorBase):
             for td in frontier:
                 groups[wavekernel.group_signature(td)].append(td)
             for g in groups.values():
-                if wavekernel.eligibility(g) is None:
+                reason = wavekernel.eligibility(g)
+                if reason is None:
                     self.kernel_dispatches += 1
                     fused.update(t.tid for t in g)
                 else:
                     self.kernel_fallbacks += 1
+                    self.kernel_fallback_reasons[reason] = \
+                        self.kernel_fallback_reasons.get(reason, 0) + 1
             nxt = []
             for td in frontier:
                 for dep in td.dependents:

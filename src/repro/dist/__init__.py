@@ -19,15 +19,11 @@ from __future__ import annotations
 import contextlib
 import threading
 
-from .. import jax_compat
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-jax_compat.install()
-
-import jax  # noqa: E402  (after compat shims)
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from .context import MeshContext  # noqa: E402
-from . import sharding  # noqa: E402,F401
+from .context import MeshContext
+from . import sharding  # noqa: F401
 
 __all__ = ["MeshContext", "use_mesh", "current", "constrain_seq",
            "constrain_heads", "sharding", "single_device_mesh"]

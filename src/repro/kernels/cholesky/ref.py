@@ -30,7 +30,10 @@ def trsm(l, a):
 def update(c, a, b):
     """Trailing update ``c - a @ b.T`` (f32/f64 accumulation)."""
     acc = jnp.promote_types(c.dtype, jnp.float32)
-    prod = jnp.matmul(a, b.T, preferred_element_type=acc)
+    # HIGHEST: f32 accuracy on the TPU's MXU, as in kernels/matmul/ref.py
+    # (XLA's own cholesky and triangular_solve already run at HIGHEST)
+    prod = jnp.matmul(a, b.T, preferred_element_type=acc,
+                      precision=jax.lax.Precision.HIGHEST)
     return (c.astype(acc) - prod).astype(c.dtype)
 
 

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -88,7 +87,7 @@ def flash_decode_pallas(q, k, v, *, scale: float | None = None,
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, k, v)

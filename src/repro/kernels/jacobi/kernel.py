@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
-
 
 def _jacobi_kernel(top_ref, mid_ref, bot_ref, out_ref, *, block_rows: int,
                    n_rows: int):
@@ -54,7 +52,7 @@ def jacobi_step_pallas(x, *, block_rows: int = 256, interpret: bool = False):
         in_specs=[spec(-1), spec(0), spec(+1)],
         out_specs=pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_rows, width), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, x, x)

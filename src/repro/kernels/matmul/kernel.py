@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
-
 
 def _mm_kernel(a_ref, b_ref, c_ref, out_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -57,7 +55,7 @@ def matmul_pallas(a, b, c, *, bm: int = 128, bn: int = 128, bk: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), c.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, c)
@@ -100,7 +98,7 @@ def tile_update_pallas(c, a, b, *, bk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((m, n), lambda i, kk: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), c.dtype),
         scratch_shapes=[pltpu.VMEM((m, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(c, a, b)

@@ -28,7 +28,7 @@ See docs/OBSERVABILITY.md for the event schema and trace workflow.
 """
 from .chrome import chrome_trace, export_chrome_trace, load_jsonl
 from .events import EVENT_FIELDS, EVENT_SCHEMA, Event, validate_event
-from .profiler import profile_session, profiler_available, trace_span
+from .profiler import profile_session, trace_span
 from .summary import mode_latency, slowest_waves, summary_table
 from .tracker import (NULL_TRACKER, ConsoleTracker, InMemoryTracker,
                       JsonlTracker, NullTracker, Tracker, TrackerBase,
@@ -41,5 +41,5 @@ __all__ = [
     "make_tracker", "validate_spec",
     "chrome_trace", "export_chrome_trace", "load_jsonl",
     "slowest_waves", "mode_latency", "summary_table",
-    "trace_span", "profile_session", "profiler_available",
+    "trace_span", "profile_session",
 ]

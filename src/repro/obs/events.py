@@ -31,8 +31,10 @@ Kinds:
   dispatches (emitted only under ``kernel_backend="pallas"``):
   ``backend`` is ``"pallas"`` (fused grid) or ``"xla"`` (fallback), and
   ``reason`` names why a fallback was taken (``"single_task"``,
-  ``"non_rectangular"``, ``"mixed_dtype"``, ``"grid_overflow"``, ...;
-  empty on the pallas path).
+  ``"non_rectangular"``, ``"mixed_dtype"``, ``"grid_overflow"``,
+  ``"vmem_budget"``, ``"compile_refused"``, ...; empty on the pallas
+  path).  A ``"compile_refused"`` fallback also carries ``error``, the
+  compiler's first error line.
 * ``queue_depth``    — a per-device (or per-worker) queue depth changed;
   the tracker keeps the live map, which the sharded executor feeds back
   into ``placement.rebalance_owners``.

@@ -5,8 +5,7 @@ wrap every wave dispatch in :func:`trace_span` — a
 ``jax.profiler.TraceAnnotation`` — so a device profile captured with
 ``jax.profiler.trace()`` (or TensorBoard) shows which XLA executions
 belong to which wave.  Disabled (the default) the span is a shared
-no-op context manager and costs nothing; if the installed jax has no
-TraceAnnotation the hook degrades to the same no-op instead of failing.
+no-op context manager and costs nothing.
 
 :func:`profile_session` is the *session* side of the same story: the
 annotations only land in a trace file if someone started a profiler
@@ -14,39 +13,25 @@ session around the run.  The benchmark driver (``benchmarks.run
 --profile-dir``) and the nightly job use it to bracket app runs with
 ``jax.profiler.start_trace``/``stop_trace`` so ``profile_waves`` spans
 end up in uploaded artifacts instead of requiring a hand-started
-TensorBoard session.  Degrades to a no-op when jax lacks the API.
+TensorBoard session.
 """
 from __future__ import annotations
 
 import contextlib
 
-__all__ = ["trace_span", "profile_session", "profiler_available"]
+from jax.profiler import TraceAnnotation, start_trace, stop_trace
+
+__all__ = ["trace_span", "profile_session"]
 
 _NULL = contextlib.nullcontext()
 
 
-def _annotation_cls():
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation
-    except Exception:
-        return None
-
-
-def profiler_available() -> bool:
-    """True when the installed jax exposes ``profiler.TraceAnnotation``."""
-    return _annotation_cls() is not None
-
-
 def trace_span(label: str, enabled: bool = True):
     """A context manager naming ``label`` in the jax profiler timeline;
-    a no-op when ``enabled`` is False or the profiler is unavailable."""
+    a no-op when ``enabled`` is False."""
     if not enabled:
         return _NULL
-    cls = _annotation_cls()
-    if cls is None:
-        return _NULL
-    return cls(label)
+    return TraceAnnotation(label)
 
 
 @contextlib.contextmanager
@@ -54,16 +39,10 @@ def profile_session(logdir: str | None):
     """Bracket a region with a ``jax.profiler`` trace session writing to
     ``logdir``; yields True when a session actually started.
 
-    No-op (yields False) when ``logdir`` is falsy or the installed jax
-    lacks ``start_trace``/``stop_trace`` — callers never need to guard.
-    ``stop_trace`` runs even if the body raises, so partial sessions
-    still flush their trace files for upload."""
+    No-op (yields False) when ``logdir`` is falsy, so callers never need
+    to guard.  ``stop_trace`` runs even if the body raises, so partial
+    sessions still flush their trace files for upload."""
     if not logdir:
-        yield False
-        return
-    try:
-        from jax.profiler import start_trace, stop_trace
-    except Exception:
         yield False
         return
     start_trace(str(logdir))

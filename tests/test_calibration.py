@@ -12,7 +12,8 @@ from repro.core.calibrate import (CalibrationError, FIG3_LATENCY_CYCLES,
                                   FIG4_SLOWDOWN, calibrate, fit_params,
                                   granularity_sweep, validate_trends)
 from repro.core.costmodel import (SCCParams, core_mc_hops,
-                                  master_core_choice, worker_order)
+                                  master_core_choice, tpu_params,
+                                  worker_order)
 from repro.core.sim import FlopcountCost, SimExecutor, SimTask, simulate
 
 import sys
@@ -226,3 +227,18 @@ class TestCalibrate:
         assert all(FIG4_SLOWDOWN[a] < FIG4_SLOWDOWN[b]
                    for a, b in zip(sorted(FIG4_SLOWDOWN),
                                    sorted(FIG4_SLOWDOWN)[1:]))
+
+
+class TestTPUPeaks:
+    def test_v5e_peaks_carry_their_source(self):
+        hw = tpu_params("TPU v5 lite")
+        assert (hw.peak_flops_bf16, hw.hbm_bw) == (197e12, 819e9)
+        assert "TPU v5e" in hw.source
+        terms = hw.roofline_terms(197e12, 819e9, 0.0)
+        assert terms == {"compute_s": 1.0, "memory_s": 1.0,
+                         "collective_s": 0.0}
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+    def test_unknown_device_kind_raises(self, kind):
+        with pytest.raises(ValueError, match="no published peaks"):
+            tpu_params(kind)

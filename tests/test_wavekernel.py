@@ -237,6 +237,71 @@ class TestEligibilityEdges:
 
 
 # ---------------------------------------------------------------------------
+class TestFallbackSplit:
+    """Only building a kernel may fall back: a group over the VMEM budget
+    or refused by the compiler takes the XLA path under its own named
+    reason, while a fault in the call of a compiled kernel propagates."""
+
+    def test_vmem_budget_reason_named(self, monkeypatch):
+        monkeypatch.setattr(wavekernel, "VMEM_BUDGET_BYTES", 1024)
+        trk = InMemoryTracker()
+        ref, _ = _gemm_run("xla")
+        out, stats = _gemm_run("pallas", tracker=trk)
+        np.testing.assert_array_equal(out, ref)
+        assert stats.kernel_dispatches == 0
+        assert stats.kernel_fallback_reasons == {"vmem_budget": stats.waves}
+        assert set(_fallback_reasons(trk)) == {"vmem_budget"}
+
+    def test_compile_refused_named_with_first_error_line(self, monkeypatch):
+        attempts = []
+
+        class Refused:
+            def lower(self, *args):
+                attempts.append(len(args))
+                raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory "
+                                   "in memory space vmem\nsecond line")
+
+        monkeypatch.setattr(wavekernel, "build_wave_kernel",
+                            lambda *a, **k: Refused())
+        trk = InMemoryTracker()
+        ref, _ = _gemm_run("xla")
+        out, stats = _gemm_run("pallas", tracker=trk)
+        np.testing.assert_array_equal(out, ref)
+        assert stats.kernel_dispatches == 0
+        assert stats.kernel_fallback_reasons == \
+            {"compile_refused": stats.waves}
+        evs = [e.data for e in trk.events_of("kernel_dispatch")]
+        assert {e["reason"] for e in evs} == {"compile_refused"}
+        assert {e["error"] for e in evs} == {
+            "_gemm: RESOURCE_EXHAUSTED: Ran out of memory in memory "
+            "space vmem"}
+        # every wave has the same group shape: one refusal, then cached
+        assert attempts == [3]
+
+    def test_device_fault_in_fused_call_propagates(self, monkeypatch):
+        def compiled_but_faulting(*args, **kwargs):
+            def call(*stacked):
+                raise RuntimeError("device fault in fused call")
+            return call
+
+        monkeypatch.setattr(wavekernel, "compile_wave_kernel",
+                            compiled_but_faulting)
+        rt = TaskRuntime(RuntimeConfig(executor="staged",
+                                       kernel_backend="pallas"))
+        with rt.scope():
+            A = rt.full((32, 32), (16, 16), 1.0)
+            C = rt.zeros((32, 32), (16, 16))
+            for i in range(2):
+                _add(C[i, 0], A[i, 0])
+            with pytest.raises(RuntimeError, match="device fault"):
+                rt.barrier()
+        stats = rt.stats()
+        rt.shutdown()
+        assert stats.kernel_fallbacks == 0
+        assert stats.kernel_dispatches == 0
+
+
+# ---------------------------------------------------------------------------
 class TestEligibilityUnit:
     def test_footprint_spec(self):
         rt = TaskRuntime(RuntimeConfig(executor="sequential"))
@@ -313,3 +378,4 @@ class TestSimCharging:
         sim = run_app("cholesky", "sim", kernel_backend="pallas")
         assert sim.kernel_dispatches == real.kernel_dispatches
         assert sim.kernel_fallbacks == real.kernel_fallbacks
+        assert sim.kernel_fallback_reasons == real.kernel_fallback_reasons
