@@ -309,8 +309,13 @@ class RuntimeConfig:
       ``"console"``, ``"jsonl"``, ``"jsonl:PATH"``) or a ready
       ``Tracker`` instance (caller-owned, shareable across runtimes).
       Every executor reports the same per-wave event schema through it.
-    * ``profile_waves`` — wrap each staged/sharded wave dispatch in a
-      ``jax.profiler.TraceAnnotation`` so device profiles name waves.
+    * ``profile_waves`` — open a ``jax.profiler.TraceAnnotation``
+      around each task's dependence analysis (``bddt/analyze``), each
+      staged/sharded wave (``bddt/<executor>/wave<k>``) and each step
+      inside it (``.../layer``, ``stack``, ``call``, ``store``,
+      ``release``), so device profiles name the host step behind every
+      gap.  Needs no tracker; :mod:`repro.obs.profiler` lists what each
+      span covers.
     * ``worker_cache_tiles`` — host executor: per-worker pinned tile
       cache capacity (entries of assembled region operands, validated by
       tile identity; 0 disables).  Hit/miss counters surface in

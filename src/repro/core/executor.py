@@ -18,6 +18,8 @@
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
 import time
 from collections import OrderedDict, defaultdict, deque
@@ -38,6 +40,8 @@ from .scheduler import MasterScheduler
 
 __all__ = ["Executor", "ExecutorBase", "SequentialExecutor", "HostExecutor",
            "StagedExecutor", "dependence_cone"]
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 @runtime_checkable
@@ -311,8 +315,15 @@ class StagedExecutor(ExecutorBase):
         self.kernel_fallbacks = 0      # pallas-requested groups gone XLA
         self.kernel_fallback_reasons: dict[str, int] = defaultdict(int)
         self._dispatches = 0           # all dispatch events this executor
-        self._wave_id = 0              # current wave (event correlation)
+        self._wave_id = 0              # current wave (events and spans)
         self._last_mode = "jit"        # how the last group dispatched
+        # profiler span labels (``profile_waves``), formatted once here
+        root = f"bddt/{self.kind}"
+        self._wave_span = root + "/wave"
+        (self._layer_span, self._stack_span, self._call_span,
+         self._store_span, self._release_span) = (
+            f"{root}/{step}"
+            for step in ("layer", "stack", "call", "store", "release"))
 
     def on_spawn(self, td: TaskDescriptor, ready: bool) -> None:
         self.pending.append(td)
@@ -453,28 +464,66 @@ class StagedExecutor(ExecutorBase):
             self._assign_outputs(
                 td, tuple(stacked[i] for stacked in result))
 
+    def _vmapped(self, fn: Callable) -> Callable:
+        vfn = self._vjit.get(fn)
+        if vfn is None:
+            vfn = self._vjit[fn] = jax.jit(jax.vmap(fn))
+        return vfn
+
+    def _task_call(self, td: TaskDescriptor, jfn: Callable,
+                   device=None) -> tuple:
+        """One task as its own dispatch: ``(jfn, operands, store)``.
+        ``device`` (if given) is the execution destination: operands
+        assemble directly on it, so jit, following its inputs, executes
+        the body on the task's owner device and resident tiles are read
+        in place."""
+        td.state = TaskState.RUNNING
+        if device is None:
+            ins = [a.region.materialize() for a in td.args if a.READS]
+            ins.extend(td.values)
+        else:
+            ins = [a.region.materialize(device=device)
+                   for a in td.args if a.READS]
+            ins.extend(jax.device_put(jnp.asarray(v), device)
+                       for v in td.values)
+        return jfn, ins, functools.partial(self._store_task, td)
+
+    def _store_task(self, td: TaskDescriptor, result) -> None:
+        self._assign_outputs(td, normalize_outputs(
+            result, len(td.outputs), td.name or td.tid))
+
+    def _calls(self, group: list[TaskDescriptor]) -> list[tuple]:
+        """Assemble the group's operands: one ``(fn, operands, store)``
+        per dispatch — the whole group through ``jit(vmap(fn))``, or each
+        task through ``jit(fn)`` when there is nothing to batch."""
+        fn = group[0].fn
+        if len(group) == 1 or not self.group:
+            jfn = self._jitted(fn)
+            return [self._task_call(td, jfn) for td in group]
+        for td in group:
+            td.state = TaskState.RUNNING
+        self._last_mode = "vmap"
+        return [(self._vmapped(fn), self._stack_group(group),
+                 functools.partial(self._store_group, group))]
+
+    def _dispatch(self, calls: list[tuple]) -> None:
+        """Enqueue every body, then commit every result."""
+        with trace_span(self._call_span, self.profile), \
+                suspend_runtime_scope():  # tracing runs fn on this thread
+            results = [fn(*ins) for fn, ins, _ in calls]
+        with trace_span(self._store_span, self.profile):
+            for (_, _, store), result in zip(calls, results):
+                store(result)
+
     def _run_group(self, group: list[TaskDescriptor]) -> None:
         if self.kernel_backend == "pallas":
             refusal = self._try_wave_kernel(group)
             if refusal is None:
                 return                 # fused pallas grid dispatched
             self._note_kernel_fallback(group, *refusal)
-        fn = group[0].fn
-        if len(group) == 1 or not self.group:
-            jfn = self._jitted(fn)
-            for td in group:
-                _run_one(td, jfn)
-            return
-        for td in group:
-            td.state = TaskState.RUNNING
-        ins = self._stack_group(group)
-        vfn = self._vjit.get(fn)
-        if vfn is None:
-            vfn = self._vjit[fn] = jax.jit(jax.vmap(fn))
-        self._last_mode = "vmap"
-        with suspend_runtime_scope():    # tracing runs fn on this thread
-            result = vfn(*ins)
-        self._store_group(group, result)
+        with trace_span(self._stack_span, self.profile):
+            calls = self._calls(group)
+        self._dispatch(calls)
 
     # -- the pallas wave-kernel backend (kernel_backend="pallas") -------------
     def _try_wave_kernel(self, group: list[TaskDescriptor]) \
@@ -494,7 +543,8 @@ class StagedExecutor(ExecutorBase):
         label = td.name or td.fn.__name__
         for t in group:
             t.state = TaskState.RUNNING
-        ins = self._stack_group(group)
+        with trace_span(self._stack_span, self.profile):
+            ins = self._stack_group(group)
         key = (td.fn, len(group),
                tuple((tuple(x.shape), str(x.dtype)) for x in ins))
         pfn = self._pjit.get(key)
@@ -509,14 +559,16 @@ class StagedExecutor(ExecutorBase):
             self._pjit[key] = pfn
         if isinstance(pfn, wavekernel.WaveKernelError):
             return pfn.reason, pfn.detail
-        result = pfn(*ins)
+        with trace_span(self._call_span, self.profile):
+            result = pfn(*ins)
         self._last_mode = "pallas"
         self.kernel_dispatches += 1
         if self.obs.enabled:
             self.obs.emit("kernel_dispatch", wave=self._wave_id,
                           executor=self.kind, fn=label, tasks=len(group),
                           backend="pallas", reason="")
-        self._store_group(group, result)
+        with trace_span(self._store_span, self.profile):
+            self._store_group(group, result)
         return None
 
     def _note_kernel_fallback(self, group: list[TaskDescriptor],
@@ -567,37 +619,46 @@ class StagedExecutor(ExecutorBase):
                       mode=self._last_mode, wall_s=wall)
 
     def _run_waves(self, tasks: list[TaskDescriptor]) -> None:
-        for wave in self._wavefronts(tasks):
+        """Layer ``tasks`` into waves and run them in order.  Two
+        independent guards: ``profile`` opens the profiler spans (wave,
+        and the layer/stack/call/store/release steps inside it), an
+        enabled tracker emits the wave and dispatch events."""
+        prof, obs = self.profile, self.obs.enabled
+        with trace_span(self._layer_span, prof):
+            waves = self._wavefronts(tasks)
+        for wave in waves:
             self.waves_run += 1
-            groups: dict = defaultdict(list)
-            for td in wave:
-                groups[self._sig(td)].append(td)
-            if self.obs.enabled:
-                self._wave_id += 1
-                wid = self._wave_id
-                self.obs.emit("wave_open", wave=wid, executor=self.kind,
-                              tasks=len(wave), groups=len(groups))
-                self._enqueue_wave(wave)
-                moves0, moved0, staged0 = self._traffic_snapshot()
-                disp0 = self._dispatches
-                t0 = time.perf_counter()
-                with trace_span(f"bddt/{self.kind}/wave{wid}", self.profile):
-                    for group in groups.values():
-                        self._run_wave_group(group)
-                wall = time.perf_counter() - t0
-                moves1, moved1, staged1 = self._traffic_snapshot()
-                self.obs.emit("wave_close", wave=wid, executor=self.kind,
-                              tasks=len(wave), wall_s=wall,
-                              dispatches=self._dispatches - disp0,
-                              tile_moves=moves1 - moves0,
-                              bytes_moved=moved1 - moved0,
-                              bytes_staged=staged1 - staged0)
-            else:
+            self._wave_id += 1
+            wid = self._wave_id
+            with (trace_span(f"{self._wave_span}{wid}") if prof
+                  else _NO_SPAN):
+                with trace_span(self._layer_span, prof):
+                    groups: dict = defaultdict(list)
+                    for td in wave:
+                        groups[self._sig(td)].append(td)
+                if obs:
+                    self.obs.emit("wave_open", wave=wid, executor=self.kind,
+                                  tasks=len(wave), groups=len(groups))
+                    self._enqueue_wave(wave)
+                    moves0, moved0, staged0 = self._traffic_snapshot()
+                    disp0 = self._dispatches
+                    t0 = time.perf_counter()
                 for group in groups.values():
-                    self._run_group(group)
-            for td in wave:
-                self.scheduler._collect(td)
-        self.scheduler.release_all()
+                    self._run_wave_group(group)
+                if obs:
+                    wall = time.perf_counter() - t0
+                    moves1, moved1, staged1 = self._traffic_snapshot()
+                    self.obs.emit("wave_close", wave=wid, executor=self.kind,
+                                  tasks=len(wave), wall_s=wall,
+                                  dispatches=self._dispatches - disp0,
+                                  tile_moves=moves1 - moves0,
+                                  bytes_moved=moved1 - moved0,
+                                  bytes_staged=staged1 - staged0)
+                with trace_span(self._release_span, prof):
+                    for td in wave:
+                        self.scheduler._collect(td)
+        with trace_span(self._release_span, prof):
+            self.scheduler.release_all()
 
     def barrier(self) -> None:
         self._run_waves(self.pending)
@@ -614,26 +675,3 @@ class StagedExecutor(ExecutorBase):
 
     def reclaim(self) -> None:
         self.barrier()
-
-
-def _run_one(td: TaskDescriptor, jfn: Callable, device=None) -> None:
-    """Run one task through a jitted function.  ``device`` (if given) is
-    the execution destination: operands assemble directly on it, so jit,
-    following its inputs, executes the body on the task's owner device
-    and resident tiles are read in place."""
-    td.state = TaskState.RUNNING
-    if device is None:
-        in_vals = [a.region.materialize() for a in td.args if a.READS]
-        values = td.values
-    else:
-        in_vals = [a.region.materialize(device=device)
-                   for a in td.args if a.READS]
-        values = tuple(jax.device_put(jnp.asarray(v), device)
-                       for v in td.values)
-    with suspend_runtime_scope():        # tracing runs fn on this thread
-        result = jfn(*in_vals, *values)
-    outs = td.outputs
-    result = normalize_outputs(result, len(outs), td.name or td.tid)
-    for mode, value in zip(outs, result):
-        mode.region.store(value)
-    td.output_values = result

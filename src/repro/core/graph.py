@@ -4,8 +4,10 @@ A spawned task becomes a :class:`TaskDescriptor` that moves through the four
 runtime stages of the paper: initiation -> scheduling -> execution -> release.
 The master keeps three structures in its private memory: the *ready queue*
 (ready, unscheduled), the *completion queue* (executed, dependencies not yet
-released) and the *task graph* (waiting on dependencies).  Descriptors come
-from a bounded pre-allocated pool and are recycled at release (§3.3).
+released) and the *task graph* (waiting on dependencies: each waiting task
+is held by the ``dependents`` list of its predecessors, and nothing else).
+Descriptors come from a bounded pre-allocated pool and are recycled at
+release (§3.3).
 """
 from __future__ import annotations
 
@@ -145,7 +147,6 @@ class TaskGraph:
     def __init__(self):
         self.ready: deque[TaskDescriptor] = deque()
         self.completion: deque[TaskDescriptor] = deque()
-        self.waiting: set[TaskDescriptor] = set()
         self.n_unreleased = 0          # live tasks not yet released
         self.n_unexecuted = 0          # live tasks not yet executed
         self._exec_counter = itertools.count()
@@ -169,7 +170,6 @@ class TaskGraph:
             td.state = TaskState.READY
             return True
         td.state = TaskState.WAITING
-        self.waiting.add(td)
         return False
 
     # -- task execution accounting -------------------------------------------
@@ -190,7 +190,6 @@ class TaskGraph:
                 # executed dependent must not re-enter the ready queue
                 # (it would pin its descriptor + outputs there forever)
                 dep.state = TaskState.READY
-                self.waiting.discard(dep)
                 newly_ready.append(dep)
         td.dependents = []
         td.preds = ()          # keep metadata O(live tasks), as in §3.6

@@ -48,6 +48,8 @@ import contextlib
 import time
 from typing import Callable, Sequence
 
+from repro.obs.profiler import trace_span
+
 from .api import (ExecutorKind, RuntimeConfig, RuntimeStats, TaskFuture,
                   _pop_runtime, _push_runtime)
 from .blocks import AccessMode, BlockArray, Region, TileTraffic, coerce_mode
@@ -60,6 +62,10 @@ from .placement import assign_homes
 from .scheduler import MasterScheduler
 
 __all__ = ["TaskRuntime"]
+
+#: the profiler span around each task's dependence analysis and graph
+#: insertion (``profile_waves``)
+ANALYZE_SPAN = "bddt/analyze"
 
 
 class TaskRuntime:
@@ -125,7 +131,7 @@ class TaskRuntime:
         self._exec: Executor = self._make_executor(config)
         self._exec.obs = self.obs
         self._exec.traffic = self.traffic
-        self._exec.profile = config.profile_waves
+        self._exec.profile = self._profile = config.profile_waves
         self._arrays: list[BlockArray] = []
         # ``repro.serve`` attaches its AdmissionController here so
         # ``stats()`` surfaces the admission_* fields; None when the
@@ -220,8 +226,9 @@ class TaskRuntime:
             td = self.pool.acquire(fn, args, name=name, values=values)
         td.spawn_order = self._spawn_counter
         self._spawn_counter += 1
-        deps = self.analyzer.analyze(td)
-        ready = self.graph.insert(td, deps)
+        with trace_span(ANALYZE_SPAN, self._profile):
+            deps = self.analyzer.analyze(td)
+            ready = self.graph.insert(td, deps)
         self._exec.on_spawn(td, ready)
         self.spawn_time_s += time.perf_counter() - t0
         return TaskFuture(self, td)

@@ -47,15 +47,17 @@ against one counted copy, the override the paper's Fig 4 numbers argue for.
 """
 from __future__ import annotations
 
+import functools
 from collections import defaultdict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .api import suspend_runtime_scope
+from repro.obs.profiler import trace_span
+
 from .blocks import DeviceTileStore
-from .executor import StagedExecutor, _run_one
+from .executor import StagedExecutor
 from .graph import TaskDescriptor, TaskState, normalize_outputs
 from .placement import device_assignment, rebalance_owners
 
@@ -163,15 +165,22 @@ class ShardedExecutor(StagedExecutor):
             self.obs.queue(home, -n)
 
     # -- dispatch -----------------------------------------------------------
-    def _run_group(self, group: list[TaskDescriptor]) -> None:
+    def _place(self, group: list[TaskDescriptor]) -> list[int]:
+        """Owner homes of ``group``, with every footprint charged."""
         owners = self._owners(group)
         for td, h in zip(group, owners):
             self._account(td, h)
+        return owners
+
+    def _run_group(self, group: list[TaskDescriptor]) -> None:
         ctx = self._mesh_ctx()
         if ctx is None:
             # single-device fallback: identical to the staged executor
             # (including its pallas wave-kernel attempt when
-            # kernel_backend="pallas" — how the CPU matrix exercises it)
+            # kernel_backend="pallas" — how the CPU matrix exercises it),
+            # so the placement takes a stack span of its own
+            with trace_span(self._stack_span, self.profile):
+                self._place(group)
             return super()._run_group(group)
         if self.kernel_backend == "pallas":
             # under a live mesh the group dispatches through the
@@ -179,29 +188,34 @@ class ShardedExecutor(StagedExecutor):
             # whole wave to one device and undo owner-computes, so the
             # mesh path is a named fallback, not a lowering attempt
             self._note_kernel_fallback(group, "sharded_mesh")
+        with trace_span(self._stack_span, self.profile):
+            calls = self._mesh_calls(group, self._place(group), ctx)
+        self._dispatch(calls)
+
+    def _mesh_calls(self, group: list[TaskDescriptor], owners: list[int],
+                    ctx) -> list[tuple]:
+        """Assemble the group's operands on the mesh: one ``(fn, operands,
+        store)`` per dispatch (the staged contract of ``_calls``)."""
         mesh = ctx.mesh
         devmap = device_assignment(self.n_homes, ctx)
         ndev = int(np.asarray(mesh.devices).size)
         if len(group) == 1 or not self.group:
             jfn = self._jitted(group[0].fn)
-            for td, h in zip(group, owners):
-                _run_one(td, jfn, device=devmap[h % len(devmap)])
-            return
+            return [self._task_call(td, jfn, device=devmap[h % len(devmap)])
+                    for td, h in zip(group, owners)]
         # sort by owner device so the sharded task axis hands each device
         # (under balanced block-cyclic homes) exactly the tasks it owns
         order = sorted(range(len(group)), key=lambda i: owners[i] % ndev)
         group = [group[i] for i in order]
         owners = [owners[i] for i in order]
         if len(group) % ndev == 0:
-            self._run_sharded(group, mesh)
-        else:
-            # a wave the mesh cannot split evenly: owner-computes
-            # sub-dispatches, one batched call per owner device
-            by_dev = defaultdict(list)
-            for td, h in zip(group, owners):
-                by_dev[devmap[h % len(devmap)]].append(td)
-            for dev, sub in by_dev.items():
-                self._run_subgroup_on(sub, dev)
+            return [self._sharded_call(group, mesh)]
+        # a wave the mesh cannot split evenly: owner-computes
+        # sub-dispatches, one batched call per owner device
+        by_dev = defaultdict(list)
+        for td, h in zip(group, owners):
+            by_dev[devmap[h % len(devmap)]].append(td)
+        return [self._subgroup_call(sub, dev) for dev, sub in by_dev.items()]
 
     def _sharded_stack(self, group: list[TaskDescriptor],
                        sharding) -> tuple[list, list]:
@@ -243,7 +257,7 @@ class ShardedExecutor(StagedExecutor):
                     group[i],
                     tuple(data[dev][i - lo] for data in shard_data))
 
-    def _run_sharded(self, group: list[TaskDescriptor], mesh) -> None:
+    def _sharded_call(self, group: list[TaskDescriptor], mesh) -> tuple:
         """The shard_map/vmap hybrid: stacked operands are sharded along
         the task axis over every mesh axis; inside each shard ``vmap``
         maps the local slice."""
@@ -262,25 +276,18 @@ class ShardedExecutor(StagedExecutor):
                 in_specs=tuple(spec for _ in ins), out_specs=spec,
                 check_vma=False))
         self._last_mode = "shard_map"
-        with suspend_runtime_scope():    # tracing runs fn on this thread
-            result = sfn(*ins)
         self.sharded_dispatches += 1
-        self._store_sharded(group, result, slices)
+        return sfn, ins, functools.partial(self._store_sharded, group,
+                                           slices=slices)
 
-    def _run_subgroup_on(self, group: list[TaskDescriptor], dev) -> None:
+    def _subgroup_call(self, group: list[TaskDescriptor], dev) -> tuple:
         """Batched vmap dispatch pinned to one owner device (the uneven-
         wave fallback; computation follows the placed operands)."""
         fn = group[0].fn
         if len(group) == 1:
-            _run_one(group[0], self._jitted(fn), device=dev)
-            return
+            return self._task_call(group[0], self._jitted(fn), device=dev)
         for td in group:
             td.state = TaskState.RUNNING
-        ins = self._stack_group(group, device=dev)
-        vfn = self._vjit.get(fn)
-        if vfn is None:
-            vfn = self._vjit[fn] = jax.jit(jax.vmap(fn))
         self._last_mode = "vmap_device"
-        with suspend_runtime_scope():
-            result = vfn(*ins)
-        self._store_group(group, result)
+        return (self._vmapped(fn), self._stack_group(group, device=dev),
+                functools.partial(self._store_group, group))

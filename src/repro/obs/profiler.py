@@ -1,11 +1,32 @@
 """Opt-in ``jax.profiler`` trace-context hook.
 
-When ``RuntimeConfig(profile_waves=True)``, the staged/sharded executors
-wrap every wave dispatch in :func:`trace_span` — a
-``jax.profiler.TraceAnnotation`` — so a device profile captured with
-``jax.profiler.trace()`` (or TensorBoard) shows which XLA executions
-belong to which wave.  Disabled (the default) the span is a shared
-no-op context manager and costs nothing.
+When ``RuntimeConfig(profile_waves=True)``, the runtime wraps each step
+of its hot path in :func:`trace_span` — a
+``jax.profiler.TraceAnnotation`` — so a profile captured with
+``jax.profiler.trace()`` (or TensorBoard) puts the host's steps on the
+same clock as the device's XLA executions.  The spans, with ``<x>`` the
+executor kind (``staged`` or ``sharded``):
+
+* ``bddt/analyze`` — one task's dependence analysis and graph insertion,
+  at its spawn;
+* ``bddt/<x>/wave<k>`` — the k-th wave the executor ran, around the
+  steps below;
+* ``bddt/<x>/layer`` — layering a barrier's (or wait's) tasks into waves,
+  and grouping one wave's tasks by signature;
+* ``bddt/<x>/stack`` — assembling one group's operands (the sharded
+  executor also places the tasks on their owner homes here);
+* ``bddt/<x>/call`` — the group's jitted body calls (they enqueue the
+  work, and compile it on a new shape);
+* ``bddt/<x>/store`` — slicing the group's results and committing them;
+* ``bddt/<x>/release`` — collecting one wave's executed tasks, and
+  releasing their dependents at the end of the barrier.
+
+The steps are leaves: a step opens inside its ``wave<k>`` span or, for
+the barrier's own layering and release, at the top.  The flag needs no
+tracker; the tracker's ``wall_s`` event fields stay on ``perf_counter``
+and only these spans line up with the device.  Disabled (the default)
+the executors pass precomputed labels and get back a shared no-op
+context manager: no annotation is built and no label formatted.
 
 :func:`profile_session` is the *session* side of the same story: the
 annotations only land in a trace file if someone started a profiler

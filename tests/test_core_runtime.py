@@ -188,6 +188,27 @@ def test_pool_exhaustion_recycles(kind):
 
 
 # ---------------------------------------------------------------------------
+# release (§3.6) keeps metadata O(live tasks): back-to-back solves on one
+# runtime leave no executed descriptor (and its output tiles) behind
+@pytest.mark.parametrize("kind", ["staged", "sharded"])
+def test_executed_descriptors_are_freed(kind):
+    import gc
+
+    from benchmarks.apps import cholesky_app
+    from repro.core.graph import TaskDescriptor
+
+    rt = TaskRuntime(executor=kind)
+    live = []
+    for _ in range(4):
+        cholesky_app(rt, n=256, tile=64, verify=False)   # 20 tasks
+        gc.collect()
+        live.append(sum(isinstance(o, TaskDescriptor)
+                        for o in gc.get_objects()))
+    rt.shutdown()
+    assert live == live[:1] * 4, live
+
+
+# ---------------------------------------------------------------------------
 # the deprecated imperative shim is gone (window closed after one PR of
 # DeprecationWarning); @task is the only spawn surface
 def test_spawn_shim_removed():

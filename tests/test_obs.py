@@ -443,10 +443,134 @@ class TestProfilerHook:
         with trace_span("bddt/test/wave1", True):
             pass
 
-    def test_profile_waves_config_plumbs(self):
-        rt = TaskRuntime(executor="staged", profile_waves=True)
-        assert rt._exec.profile is True
+    @pytest.mark.parametrize("executor", ["staged", "sharded"])
+    @pytest.mark.parametrize("profile", [True, False], ids=["on", "off"])
+    def test_profile_waves_config_plumbs(self, executor, profile):
+        """``profile_waves`` alone, with no tracker, opens the program
+        spans: the analysis per task, and per wave the layering, each
+        group's stack, call and store, and the release.  The steps are
+        leaves under their wave (or at the barrier's top level), and each
+        wave has one stack, call and store span per group dispatched,
+        each call span holding that group's jitted body calls.  Off, no
+        annotation is built at all.  The sharded case runs on a forced
+        2-device mesh, where an uneven group makes one call per device."""
+        if executor == "staged":
+            got = _record_spans(executor, profile)
+        else:
+            got = _record_spans_on_mesh(executor, profile)
+        log = got["log"]
+        spans = [(i, e[1], e[2]) for i, e in enumerate(log) if e[0] == "span"]
+        calls = [e[1] for e in log if e[0] == "dispatch"]
+        assert got["profile"] is profile
+        assert calls                                    # the body ran
+        if not profile:
+            assert spans == []
+            return
+        kind = f"bddt/{executor}"
+        name = {i: n for i, n, _ in spans}
+        waves = [i for i, n, _ in spans if n.startswith(f"{kind}/wave")]
+        assert len({name[w] for w in waves}) == len(waves) == got["waves"]
+        assert {n for _, n, _ in spans} == {
+            "bddt/analyze", *(name[w] for w in waves),
+            *(f"{kind}/{s}" for s in ("layer", "stack", "call", "store",
+                                      "release"))}
+        for i, n, parent in spans:
+            # waves at the top; every step a leaf, in a wave or at the top
+            assert parent is None or (i not in waves and parent in waves)
+        assert [n for _, n, _ in spans].count("bddt/analyze") == got["tasks"]
+        # every body call inside a call span, directly in a wave
+        assert all(name[c] == f"{kind}/call" for c in calls)
+        n_groups = 0
+        for w in waves:
+            inside = [n for _, n, p in spans if p == w]
+            in_call = {i for i, n, p in spans
+                       if p == w and n == f"{kind}/call"}
+            assert in_call and in_call <= set(calls), name[w]
+            for s in ("stack", "call", "store"):
+                assert inside.count(f"{kind}/{s}") == len(in_call), \
+                    (name[w], s)
+            assert inside.count(f"{kind}/layer") == 1
+            assert inside.count(f"{kind}/release") == 1
+            n_groups += len(in_call)
+        if executor == "staged":                  # one call per group
+            assert len(calls) == n_groups
+        else:                                     # uneven groups split
+            assert len(calls) > n_groups
+        top = [n for _, n, p in spans if p is None and n != "bddt/analyze"]
+        assert top.count(f"{kind}/layer") == top.count(f"{kind}/release") \
+            == 1                                    # one barrier
+
+
+def _record_spans(executor: str, profile: bool) -> dict:
+    """Two 4x4-tile Cholesky solves on one runtime with no tracker; the
+    second is recorded: each annotation as ``["span", name, parent]``
+    through a stand-in for ``TraceAnnotation``, and each jitted body call
+    as ``["dispatch", innermost open span]`` by wrapping the executor's
+    compiled bodies (cached by the first solve); a span is named by its
+    place in the log."""
+    from benchmarks.apps import cholesky_app
+    from repro.obs import profiler
+
+    log, stack = [], []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            stack.append(len(log))
+            log.append(["span", self.name, stack[-2] if stack[1:] else None])
+
+        def __exit__(self, *exc):
+            stack.pop()
+
+    def counted(fn):
+        def call(*args):
+            log.append(["dispatch", stack[-1] if stack else None])
+            return fn(*args)
+        return call
+
+    real = profiler.TraceAnnotation
+    profiler.TraceAnnotation = Recorder
+    try:
+        rt = TaskRuntime(executor=executor, profile_waves=profile,
+                         n_controllers=2)
+        cholesky_app(rt, n=64, tile=16)
+        ex = rt._exec
+        for cache in (ex._jit, ex._vjit, getattr(ex, "_smap", {})):
+            for key in cache:
+                cache[key] = counted(cache[key])
+        before = rt.stats()
+        del log[:]
+        cholesky_app(rt, n=64, tile=16)
+        after = rt.stats()
         rt.shutdown()
+    finally:
+        profiler.TraceAnnotation = real
+    return {"log": log, "profile": ex.profile,
+            "tasks": after.tasks_spawned - before.tasks_spawned,
+            "waves": after.waves - before.waves}
+
+
+def _record_spans_on_mesh(executor: str, profile: bool) -> dict:
+    code = f"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json, sys
+sys.path[:0] = ["src", ".", "tests"]
+import jax, numpy as np
+from repro import dist
+import test_obs
+assert jax.device_count() == 2
+mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("data",))
+with dist.use_mesh(mesh):
+    print(json.dumps(test_obs._record_spans({executor!r}, {profile!r})))
+"""
+    out = subprocess.run([sys.executable, "-c", code],
+                         cwd=pathlib.Path(__file__).resolve().parent.parent,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 # ---------------------------------------------------------------------------
