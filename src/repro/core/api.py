@@ -36,8 +36,8 @@ never synchronized on::
 
 Because the function object is shared across spawn sites (no per-value
 closures), the staged executor batches same-shape instances of a wavefront
-into one ``jit(vmap(fn))`` dispatch, stacking the firstprivate values as
-extra vmap operands.
+into one device program (``vmap(fn)`` under one ``jit``), stacking the
+firstprivate values as extra vmap operands.
 
 Calling a decorated function *outside* a runtime scope (or from a worker
 thread) with plain arrays runs it eagerly — the decorated function is its
@@ -420,6 +420,9 @@ class RuntimeStats:
     # staged / sharded executors
     waves: int | None = None
     grouped_dispatches: int | None = None
+    # tasks dispatched through a group program (groups of width >= 2 on
+    # the XLA path: stack, vmapped body and unstack in one device program)
+    group_program_tasks: int | None = None
     # wave-kernel backend (kernel_backend="pallas"): groups fused into one
     # pallas grid vs groups that took the XLA fallback, and the fallbacks
     # counted by reason ("single_task", "vmem_budget", "compile_refused",
@@ -821,7 +824,7 @@ def task(fn: Callable | None = None, *, in_=(), out=(), inout=(),
     firstprivate parameter may declare a default, used when the spawn
     site omits it.  On the staged executor, same-function tasks of a
     wavefront that differ only in firstprivate values batch into one
-    ``jit(vmap(fn))`` dispatch with the values stacked as vmap operands —
+    device program, ``vmap(fn)`` with the values stacked as operands —
     so the body must be vmap-traceable over them (index with
     ``jax.lax.dynamic_slice``, not Python slicing).
     """

@@ -286,13 +286,18 @@ class StagedExecutor(ExecutorBase):
     dispatches each layer as batched jitted calls.
 
     Grouping: tasks in one wavefront with the same function and the same
-    input/output signature are stacked and executed through one
-    ``jit(vmap(fn))`` call — the TPU analogue of handing each worker its MPB
-    queue of identical tile tasks.  Firstprivate values are stacked as extra
-    vmap operands, so index-parameterized tile tasks (same function,
-    different offsets) share the dispatch too.  The stacked axis is the
-    "worker" axis; under ``shard_map`` on real hardware it shards over the
-    mesh.
+    input/output signature run as one device program per group — the TPU
+    analogue of handing each worker its MPB queue of identical tile tasks.
+    The group program (:meth:`_group_program`, one jit per body, traced
+    per group shape) takes the group's operand tiles as they are, stacks
+    them, applies ``vmap(fn)`` and hands each output back as a tuple of
+    per-task values: the stack and the per-task slices run inside the one
+    dispatch, not as host-dispatched operations of their own.
+    Firstprivate values are stacked on the host, one array per value
+    position, as extra vmap operands, so index-parameterized tile tasks
+    (same function, different offsets) share the dispatch too.  The
+    stacked axis is the "worker" axis; under ``shard_map`` on real
+    hardware it shards over the mesh.
     """
 
     kind = "staged"
@@ -311,6 +316,7 @@ class StagedExecutor(ExecutorBase):
         self._pjit: dict[tuple, Callable | wavekernel.WaveKernelError] = {}
         self.waves_run = 0
         self.grouped_dispatches = 0
+        self.group_program_tasks = 0   # tasks run through a group program
         self.kernel_dispatches = 0     # groups fused into one pallas grid
         self.kernel_fallbacks = 0      # pallas-requested groups gone XLA
         self.kernel_fallback_reasons: dict[str, int] = defaultdict(int)
@@ -411,9 +417,10 @@ class StagedExecutor(ExecutorBase):
     def _pulls(group: list[TaskDescriptor]) -> list:
         """One ``(element_shape, pull(i, device))`` pair per stacked
         operand — READS args then firstprivate values, the canonical
-        stacking order shared by the staged and sharded dispatch paths.
+        stacking order of the eagerly stacked paths (the pallas grid and
+        the sharded shard_map split; the group program keeps it too).
         ``pull(i, device)`` produces task ``i``'s operand assembled on
-        ``device`` (left in place when None, the plain staged path)."""
+        ``device`` (left in place when None, the pallas grid)."""
         pulls = []
         for pos in range(len(group[0].args)):
             if not group[0].args[pos].READS:
@@ -431,18 +438,11 @@ class StagedExecutor(ExecutorBase):
                                         dev)))
         return pulls
 
-    def _stack_group(self, group: list[TaskDescriptor],
-                     device=None) -> list:
+    def _stack_group(self, group: list[TaskDescriptor]) -> list:
         """Stack each READS arg across the group, then the firstprivate
-        values as extra vmap operands — same function, different index
-        values, one compiled dispatch per wavefront.  ``device`` (if
-        given) is the dispatch destination: each operand is assembled
-        *directly on it* (``Region.materialize(device=...)``), so tiles
-        resident on other devices move exactly once and nothing routes
-        through a staging device.  The sharded executor passes the owner
-        device here; the plain staged path leaves operands where they
-        are."""
-        return [jnp.stack([pull(i, device) for i in range(len(group))])
+        values, as eager device arrays: the operands of a fused pallas
+        wave kernel, which takes them stacked."""
+        return [jnp.stack([pull(i, None) for i in range(len(group))])
                 for _, pull in self._pulls(group)]
 
     @staticmethod
@@ -455,8 +455,11 @@ class StagedExecutor(ExecutorBase):
         td.output_values = vals
 
     def _store_group(self, group: list[TaskDescriptor], result) -> None:
-        """Unstack one batched result back into the group's regions and
-        captured outputs (one slice per task, in group order)."""
+        """Commit one batched result to the group's regions and captured
+        outputs, in group order: the grouped commit point.  Each output's
+        entry is indexable by task — a stacked array (pallas grid), or a
+        tuple of per-task values (group program), where ``[i]`` is plain
+        Python indexing and no device operation."""
         result = normalize_outputs(result, len(group[0].outputs),
                                    group[0].name or group[0].tid)
         self.grouped_dispatches += 1
@@ -464,11 +467,49 @@ class StagedExecutor(ExecutorBase):
             self._assign_outputs(
                 td, tuple(stacked[i] for stacked in result))
 
-    def _vmapped(self, fn: Callable) -> Callable:
-        vfn = self._vjit.get(fn)
-        if vfn is None:
-            vfn = self._vjit[fn] = jax.jit(jax.vmap(fn))
-        return vfn
+    def _group_program(self, fn: Callable) -> Callable:
+        """The one device program that runs a group of ``fn`` tasks:
+        ``program(reads, values)`` takes per READS position the group's
+        tiles in task order and per firstprivate position the values
+        stacked on the host, stacks the tiles, applies ``vmap(fn)`` and
+        returns each output as a tuple of per-task values.  It carries
+        ``fn``'s name, so the device trace names it ``jit_<fn>``.  The
+        values enter strongly typed in their canonical dtype."""
+        program = self._vjit.get(fn)
+        if program is None:
+            vfn = jax.vmap(fn)
+
+            @functools.wraps(fn)
+            def run(reads, values):
+                out = vfn(*(jnp.stack(tiles) for tiles in reads), *values)
+                return jax.tree.map(lambda x: tuple(jnp.unstack(x)), out)
+
+            program = self._vjit[fn] = jax.jit(run)
+        return program
+
+    def _group_call(self, group: list[TaskDescriptor],
+                    device=None) -> tuple:
+        """The whole group as one dispatch of its group program:
+        ``(program, (reads, values), store)``.  ``device`` (if given) is
+        the execution destination: each tile is assembled *directly on
+        it* (``Region.materialize(device=...)``), so tiles resident
+        elsewhere move exactly once, and the jit follows its committed
+        inputs there; the plain staged path leaves tiles where they are.
+        Each firstprivate position is one host-stacked array, one
+        transfer per group."""
+        for td in group:
+            td.state = TaskState.RUNNING
+        reads = tuple(
+            tuple(td.args[pos].region.materialize(device=device)
+                  for td in group)
+            for pos, arg in enumerate(group[0].args) if arg.READS)
+        values = tuple(np.stack([td.values[pos] for td in group])
+                       for pos in range(len(group[0].values)))
+        if device is not None:
+            values = jax.device_put(values, device)
+        self.group_program_tasks += len(group)
+        return (self._group_program(group[0].fn), (reads, values),
+                functools.partial(self._store_group, group))
 
     def _task_call(self, td: TaskDescriptor, jfn: Callable,
                    device=None) -> tuple:
@@ -494,17 +535,13 @@ class StagedExecutor(ExecutorBase):
 
     def _calls(self, group: list[TaskDescriptor]) -> list[tuple]:
         """Assemble the group's operands: one ``(fn, operands, store)``
-        per dispatch — the whole group through ``jit(vmap(fn))``, or each
+        per dispatch — the whole group through its group program, or each
         task through ``jit(fn)`` when there is nothing to batch."""
-        fn = group[0].fn
         if len(group) == 1 or not self.group:
-            jfn = self._jitted(fn)
+            jfn = self._jitted(group[0].fn)
             return [self._task_call(td, jfn) for td in group]
-        for td in group:
-            td.state = TaskState.RUNNING
         self._last_mode = "vmap"
-        return [(self._vmapped(fn), self._stack_group(group),
-                 functools.partial(self._store_group, group))]
+        return [self._group_call(group)]
 
     def _dispatch(self, calls: list[tuple]) -> None:
         """Enqueue every body, then commit every result."""

@@ -352,6 +352,7 @@ class TaskRuntime:
         if isinstance(self._exec, StagedExecutor):
             s.waves = self._exec.waves_run
             s.grouped_dispatches = self._exec.grouped_dispatches
+            s.group_program_tasks = self._exec.group_program_tasks
         # wave-kernel backend counters, duck-typed so any executor that
         # routes groups through the pallas layer (staged/sharded real,
         # sim predicted) reports the same fields; inert under "xla"

@@ -281,13 +281,10 @@ class ShardedExecutor(StagedExecutor):
                                            slices=slices)
 
     def _subgroup_call(self, group: list[TaskDescriptor], dev) -> tuple:
-        """Batched vmap dispatch pinned to one owner device (the uneven-
-        wave fallback; computation follows the placed operands)."""
-        fn = group[0].fn
+        """The group program pinned to one owner device (the uneven-wave
+        fallback; computation follows the placed operands)."""
         if len(group) == 1:
-            return self._task_call(group[0], self._jitted(fn), device=dev)
-        for td in group:
-            td.state = TaskState.RUNNING
+            return self._task_call(group[0], self._jitted(group[0].fn),
+                                   device=dev)
         self._last_mode = "vmap_device"
-        return (self._vmapped(fn), self._stack_group(group, device=dev),
-                functools.partial(self._store_group, group))
+        return self._group_call(group, device=dev)

@@ -3,7 +3,7 @@
 The paper's §3.2 performance argument is that a wave's tasks should run
 out of fast on-chip memory (the per-core MPBs) instead of round-tripping
 every operand through shared DRAM.  The staged executor already fuses a
-wavefront's identical tile tasks into one ``jit(vmap(fn))`` dispatch; this
+wavefront's identical tile tasks into one ``vmap(fn)`` program; this
 module goes one level down: an eligible group lowers into a *single*
 Pallas kernel whose grid axis is the task axis — ``grid=(n_tasks,)`` —
 and whose ``BlockSpec``s map each task's block footprint onto the stacked

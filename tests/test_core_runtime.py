@@ -8,6 +8,12 @@ Task programs are built on the declarative ``@task`` front-end
 imperative ``rt.spawn(fn, In(...), ...)`` shim is gone — one test below
 pins the removal.
 """
+import functools
+import json
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -329,3 +335,166 @@ def test_placement_single_contended():
     rt = TaskRuntime(executor="sequential", placement="single")
     A = rt.zeros((32, 32), (4, 4))
     assert home_histogram(A, 4) == [64, 0, 0, 0]
+
+
+# ---------------------------------------------------------------------------
+# one device program per wave group: the stack, the vmapped body and the
+# per-task unstack run inside one jit, so the barrier dispatches no eager
+# stack and no per-task slice on the XLA path
+def _group_program_probe(executor: str) -> dict:
+    """Run ``cholesky_app`` (n=64, tile=16: 20 tasks) once to trace every
+    group shape, then again with eager ``jnp.stack`` and
+    ``jax.Array.__getitem__`` patched to raise inside the barrier.  On a
+    mesh the even shard_map split (``ShardedExecutor._sharded_call``)
+    still stacks and reads its shards eagerly, and is exempt."""
+    import contextlib
+    import functools
+    from unittest import mock
+
+    import jax
+    from benchmarks.apps import cholesky_app
+    from repro.core.sharded import ShardedExecutor
+
+    want = np.asarray(cholesky_app(TaskRuntime(executor="sequential"),
+                                   n=64, tile=16, verify=False).gather())
+    rt = TaskRuntime(executor=executor, n_controllers=2)
+    cholesky_app(rt, n=64, tile=16, verify=False)
+    ex = rt._exec
+    widths = []
+
+    def counted(program):
+        def call(reads, values):
+            widths.append(len(reads[0]))
+            return program(reads, values)
+        return call
+
+    for fn in ex._vjit:
+        ex._vjit[fn] = counted(ex._vjit[fn])
+
+    exempt = [False]
+
+    def exempting(method):
+        @functools.wraps(method)
+        def call(*args, **kwargs):
+            exempt[0] = True
+            try:
+                return method(*args, **kwargs)
+            finally:
+                exempt[0] = False
+        return call
+
+    real_stack = jnp.stack
+    array_type = type(jnp.zeros(1))
+    real_getitem = array_type.__getitem__
+
+    def stack(arrays, *args, **kwargs):
+        arrays = list(arrays)
+        if not exempt[0] and not all(isinstance(a, jax.core.Tracer)
+                                     for a in arrays):
+            raise AssertionError("eager jnp.stack in the barrier")
+        return real_stack(arrays, *args, **kwargs)
+
+    def getitem(self, idx):
+        if not exempt[0]:
+            raise AssertionError("eager per-task slice in the barrier")
+        return real_getitem(self, idx)
+
+    barrier = ex.barrier
+
+    def patched_barrier():
+        with contextlib.ExitStack() as patches:
+            patches.enter_context(mock.patch.object(jnp, "stack", stack))
+            patches.enter_context(
+                mock.patch.object(array_type, "__getitem__", getitem))
+            for name in ("_sharded_stack", "_store_sharded"):
+                patches.enter_context(mock.patch.object(
+                    ShardedExecutor, name,
+                    exempting(getattr(ShardedExecutor, name))))
+            barrier()
+
+    ex.barrier = patched_barrier
+    before = rt.stats()
+    got = np.asarray(cholesky_app(rt, n=64, tile=16, verify=False).gather())
+    after = rt.stats()
+    rt.shutdown()
+    return {"bit_identical": bool(np.array_equal(got, want)),
+            "group_program_tasks": (after.group_program_tasks
+                                    - before.group_program_tasks),
+            "program_widths": widths,
+            "sharded_dispatches": after.sharded_dispatches or 0}
+
+
+@functools.lru_cache(maxsize=None)
+def _group_program_run(executor: str) -> dict:
+    """The probe: staged in this process, sharded on a forced 2-device
+    mesh in a subprocess (the device count is fixed before JAX starts)."""
+    if executor == "staged":
+        return _group_program_probe(executor)
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json, sys
+sys.path[:0] = ["src", ".", "tests"]
+import jax, numpy as np
+import conftest, test_core_runtime
+from repro import dist
+assert jax.device_count() == 2
+mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("data",))
+with dist.use_mesh(mesh):
+    print(json.dumps(test_core_runtime._group_program_probe("sharded")))
+"""
+    out = subprocess.run([sys.executable, "-c", code],
+                         cwd=pathlib.Path(__file__).resolve().parent.parent,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("executor", ["staged", "sharded"])
+def test_group_program_runs_no_eager_stack_or_slice(executor):
+    # the probe's patches raise on an eager stack or per-task slice
+    run = _group_program_run(executor)
+    assert run["program_widths"]
+    if executor == "sharded":
+        # both mesh paths ran: the even split and per-owner group programs
+        assert run["sharded_dispatches"] > 0
+
+
+@pytest.mark.parametrize("executor", ["staged", "sharded"])
+def test_group_program_tasks_counts_grouped_tasks(executor):
+    run = _group_program_run(executor)
+    assert min(run["program_widths"]) >= 2
+    assert run["group_program_tasks"] == sum(run["program_widths"])
+    if executor == "staged":
+        # 20 tasks: the 4 potrf and the width-1 trsm and update of the
+        # last step run alone
+        assert run["group_program_tasks"] == 20 - 4 - 2
+
+
+@pytest.mark.parametrize("executor", ["staged", "sharded"])
+def test_group_program_bit_identical_to_sequential(executor):
+    assert _group_program_run(executor)["bit_identical"]
+
+
+def test_store_group_commits_stacked_or_per_task_values():
+    """The grouped commit point takes each output as a stacked array
+    (pallas grid) or a tuple of per-task values (group program)."""
+    rt = TaskRuntime(executor="staged")
+    with rt.scope():
+        src = rt.full((4, 12), (4, 4), 1.0)
+        dst = rt.zeros((4, 12), (4, 4))
+        for j in range(3):
+            _scale(src[0, j], dst[0, j])
+    group = list(rt._exec.pending)
+    vals = [jnp.full((4, 4), float(j + 1)) for j in range(3)]
+    committed = []
+    for result in (jnp.stack(vals), tuple(vals)):
+        rt._exec._store_group(group, result)
+        committed.append([np.asarray(dst.get_tile((0, j)))
+                          for j in range(3)])
+        assert [np.asarray(td.output_values[0]).tolist()
+                for td in group] == [v.tolist() for v in committed[-1]]
+    for j in range(3):
+        np.testing.assert_array_equal(committed[0][j], vals[j])
+        np.testing.assert_array_equal(committed[1][j], vals[j])
+    rt.shutdown()

@@ -276,8 +276,10 @@ class TestDeterministicCounts:
         # the to_dict schema: it parses, and every deterministic counter
         # matches the stats() the program saw
         got = RuntimeStats.from_dict(ev.data["stats"])
+        assert got.group_program_tasks
         for f in ("tasks_spawned", "deps_found", "waves",
-                  "grouped_dispatches", "tile_moves", "bytes_moved",
+                  "grouped_dispatches", "group_program_tasks",
+                  "tile_moves", "bytes_moved",
                   "bytes_staged", "region_waits", "futures_resolved"):
             assert getattr(got, f) == getattr(stats, f), f
 
