@@ -423,6 +423,12 @@ class RuntimeStats:
     # tasks dispatched through a group program (groups of width >= 2 on
     # the XLA path: stack, vmapped body and unstack in one device program)
     group_program_tasks: int | None = None
+    # the READS operands handed to those group programs, one per task and
+    # READS position, and the distinct ones among each group's operands
+    # (keyed by array and tile range), summed over groups: in a tiled
+    # GEMM wave every A and B tile feeds a whole row or column of tasks
+    group_operand_tiles: int | None = None
+    group_distinct_tiles: int | None = None
     # wave-kernel backend (kernel_backend="pallas"): groups fused into one
     # pallas grid vs groups that took the XLA fallback, and the fallbacks
     # counted by reason ("single_task", "vmem_budget", "compile_refused",

@@ -317,6 +317,8 @@ class StagedExecutor(ExecutorBase):
         self.waves_run = 0
         self.grouped_dispatches = 0
         self.group_program_tasks = 0   # tasks run through a group program
+        self.group_operand_tiles = 0   # READS operands passed to them
+        self.group_distinct_tiles = 0  # distinct operands, per group
         self.kernel_dispatches = 0     # groups fused into one pallas grid
         self.kernel_fallbacks = 0      # pallas-requested groups gone XLA
         self.kernel_fallback_reasons: dict[str, int] = defaultdict(int)
@@ -499,15 +501,18 @@ class StagedExecutor(ExecutorBase):
         transfer per group."""
         for td in group:
             td.state = TaskState.RUNNING
-        reads = tuple(
-            tuple(td.args[pos].region.materialize(device=device)
-                  for td in group)
-            for pos, arg in enumerate(group[0].args) if arg.READS)
+        regions = [[td.args[pos].region for td in group]
+                   for pos, arg in enumerate(group[0].args) if arg.READS]
+        reads = tuple(tuple(r.materialize(device=device) for r in rs)
+                      for rs in regions)
         values = tuple(np.stack([td.values[pos] for td in group])
                        for pos in range(len(group[0].values)))
         if device is not None:
             values = jax.device_put(values, device)
         self.group_program_tasks += len(group)
+        self.group_operand_tiles += len(group) * len(regions)
+        self.group_distinct_tiles += len({(r.array.array_id, r.ranges)
+                                          for rs in regions for r in rs})
         return (self._group_program(group[0].fn), (reads, values),
                 functools.partial(self._store_group, group))
 

@@ -353,6 +353,8 @@ class TaskRuntime:
             s.waves = self._exec.waves_run
             s.grouped_dispatches = self._exec.grouped_dispatches
             s.group_program_tasks = self._exec.group_program_tasks
+            s.group_operand_tiles = self._exec.group_operand_tiles
+            s.group_distinct_tiles = self._exec.group_distinct_tiles
         # wave-kernel backend counters, duck-typed so any executor that
         # routes groups through the pallas layer (staged/sharded real,
         # sim predicted) reports the same fields; inert under "xla"

@@ -277,8 +277,10 @@ class TestDeterministicCounts:
         # matches the stats() the program saw
         got = RuntimeStats.from_dict(ev.data["stats"])
         assert got.group_program_tasks
+        assert got.group_operand_tiles and got.group_distinct_tiles
         for f in ("tasks_spawned", "deps_found", "waves",
                   "grouped_dispatches", "group_program_tasks",
+                  "group_operand_tiles", "group_distinct_tiles",
                   "tile_moves", "bytes_moved",
                   "bytes_staged", "region_waits", "futures_resolved"):
             assert getattr(got, f) == getattr(stats, f), f
