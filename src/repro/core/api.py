@@ -457,6 +457,11 @@ class RuntimeStats:
     tile_moves: int | None = None
     bytes_moved: int | None = None
     bytes_staged: int | None = None
+    # the memory layer's input tiling (``BlockArray.scatter`` and
+    # ``from_array``): one split program per whole array, and the tiles
+    # those programs cut; counted by every executor's arrays alike
+    split_programs: int | None = None
+    tiles_split: int | None = None
     # sharded dependence manager: total dep_query/dep_grant/release
     # messages over the MPB channels, and per-manager admission counts
     # (None under the central analyzer).  ``dep_messages`` counts

@@ -21,6 +21,7 @@ them, never staged through an intermediate device — the paper's
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator, Sequence
@@ -79,15 +80,21 @@ class TileTraffic:
       intermediate hop.  The device-resident executors keep this at zero;
       a nonzero value means some path still stages.
     * ``bytes_local`` — reads served in place on the requesting device.
+    * ``split_programs`` / ``tiles_split`` — whole arrays cut into their
+      tiles by one device program (``scatter``, ``from_array``), and the
+      tiles those programs produced.
     """
     tile_moves: int = 0
     bytes_moved: int = 0
     bytes_staged: int = 0
     bytes_local: int = 0
+    split_programs: int = 0
+    tiles_split: int = 0
 
     def reset(self) -> None:
         self.tile_moves = self.bytes_moved = 0
         self.bytes_staged = self.bytes_local = 0
+        self.split_programs = self.tiles_split = 0
 
 
 def _majority_device(tiles: list):
@@ -134,6 +141,20 @@ def _pull_tiles(tiles: list, device, traffic: TileTraffic | None,
     return out
 
 
+@functools.partial(jax.jit, static_argnames="block_shape")
+def _split_tiles(arr, block_shape: tuple[int, ...]) -> tuple:
+    """Every tile of ``arr`` in ``BlockArray.block_indices`` order, cut by
+    one device program: static slices, so each tile is one copy and the
+    whole split reads and writes the array once.  Cached by JAX on shape,
+    dtype and ``block_shape``; the input is never donated, since callers
+    reload the same array again and again."""
+    grid = [s // b for s, b in zip(arr.shape, block_shape)]
+    return tuple(
+        arr[tuple(slice(i * b, (i + 1) * b)
+                  for i, b in zip(idx, block_shape))]
+        for idx in itertools.product(*map(range, grid)))
+
+
 # ---------------------------------------------------------------------------
 # tile storage backends
 class TileStore:
@@ -154,6 +175,12 @@ class TileStore:
 
     def set(self, idx: tuple[int, ...], value) -> None:
         self._tiles[idx] = value
+
+    def set_many(self, items: dict) -> None:
+        """Commit many tiles at once (``{idx: value}``), charged exactly as
+        one ``set`` per tile."""
+        for idx, value in items.items():
+            self.set(idx, value)
 
     def device_for(self, idx: tuple[int, ...]):
         """The residency target of tile ``idx`` (None = host/uncommitted)."""
@@ -189,13 +216,25 @@ class DeviceTileStore(TileStore):
         home = self.array.home.get(idx, 0)
         return self.devmap[home % len(self.devmap)]
 
-    def set(self, idx: tuple[int, ...], value) -> None:
-        dest = self.device_for(idx)
+    def _charge(self, value, dest) -> None:
         src = device_of(value)
         if src is not None and src != dest and self.traffic is not None:
             self.traffic.tile_moves += 1
             self.traffic.bytes_moved += self.array.tile_nbytes
+
+    def set(self, idx: tuple[int, ...], value) -> None:
+        dest = self.device_for(idx)
+        self._charge(value, dest)
         self._tiles[idx] = jax.device_put(value, dest)
+
+    def set_many(self, items: dict) -> None:
+        """One batched ``device_put`` homes every tile, in place of one
+        call per tile; each tile is charged as ``set`` would charge it."""
+        dests = [self.device_for(idx) for idx in items]
+        for value, dest in zip(items.values(), dests):
+            self._charge(value, dest)
+        self._tiles.update(zip(items, jax.device_put(list(items.values()),
+                                                     dests)))
 
 
 # ---------------------------------------------------------------------------
@@ -262,11 +301,14 @@ class BlockArray:
     # -- construction -----------------------------------------------------
     @classmethod
     def from_array(cls, arr, block_shape: Sequence[int],
-                   name: str | None = None) -> "BlockArray":
+                   name: str | None = None,
+                   traffic: TileTraffic | None = None) -> "BlockArray":
+        """Tile ``arr`` (one split program, see ``scatter``); ``traffic``
+        is the recorder the split is counted in, if any."""
         arr = jnp.asarray(arr)
         ba = cls(arr.shape, block_shape, arr.dtype, name=name)
-        for idx in ba.block_indices():
-            ba._store.set(idx, arr[ba._tile_slices(idx)])
+        ba.traffic = traffic
+        ba.scatter(arr)
         return ba
 
     @classmethod
@@ -286,10 +328,6 @@ class BlockArray:
     # -- indexing ----------------------------------------------------------
     def block_indices(self) -> Iterator[tuple[int, ...]]:
         return itertools.product(*[range(g) for g in self.grid])
-
-    def _tile_slices(self, idx: tuple[int, ...]) -> tuple[slice, ...]:
-        return tuple(slice(i * b, (i + 1) * b)
-                     for i, b in zip(idx, self.block_shape))
 
     def __getitem__(self, key) -> "Region":
         """``A[i, j]`` (one tile) or ``A[i0:i1, j]`` (tile range) -> Region.
@@ -351,12 +389,17 @@ class BlockArray:
         return jnp.block(nested.tolist())
 
     def scatter(self, arr) -> None:
-        """Overwrite all tiles from a full array."""
+        """Overwrite all tiles from a full array: one split program cuts
+        every tile, one ``set_many`` commits them.  The tiles are as
+        committed as ``arr`` is, as per-tile slices would be."""
         arr = jnp.asarray(arr)
         if arr.shape != self.shape:
             raise ValueError("scatter shape mismatch")
-        for idx in self.block_indices():
-            self._store.set(idx, arr[self._tile_slices(idx)])
+        tiles = _split_tiles(arr, self.block_shape)
+        self._store.set_many(dict(zip(self.block_indices(), tiles)))
+        if self.traffic is not None:
+            self.traffic.split_programs += 1
+            self.traffic.tiles_split += len(tiles)
 
     def __repr__(self):
         return (f"BlockArray({self.name}, shape={self.shape}, "

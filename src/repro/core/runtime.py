@@ -197,7 +197,8 @@ class TaskRuntime:
 
     def from_array(self, arr, block_shape: Sequence[int],
                    name: str | None = None) -> BlockArray:
-        return self._register(BlockArray.from_array(arr, block_shape, name))
+        return self._register(BlockArray.from_array(arr, block_shape, name,
+                                                    traffic=self.traffic))
 
     def zeros(self, shape, block_shape, dtype=None,
               name: str | None = None) -> BlockArray:
@@ -369,6 +370,8 @@ class TaskRuntime:
         s.tile_moves = self.traffic.tile_moves
         s.bytes_moved = self.traffic.bytes_moved
         s.bytes_staged = self.traffic.bytes_staged
+        s.split_programs = self.traffic.split_programs
+        s.tiles_split = self.traffic.tiles_split
         # duck-typed (like last_result below) so the single-machine path
         # never imports the sharded module just to fill in stats
         if getattr(self._exec, "cross_home_bytes", None) is not None:

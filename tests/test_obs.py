@@ -278,11 +278,13 @@ class TestDeterministicCounts:
         got = RuntimeStats.from_dict(ev.data["stats"])
         assert got.group_program_tasks
         assert got.group_operand_tiles and got.group_distinct_tiles
+        assert (got.split_programs, got.tiles_split) == (2, 8)
         for f in ("tasks_spawned", "deps_found", "waves",
                   "grouped_dispatches", "group_program_tasks",
                   "group_operand_tiles", "group_distinct_tiles",
                   "tile_moves", "bytes_moved",
-                  "bytes_staged", "region_waits", "futures_resolved"):
+                  "bytes_staged", "split_programs", "tiles_split",
+                  "region_waits", "futures_resolved"):
             assert getattr(got, f) == getattr(stats, f), f
 
 
@@ -583,6 +585,8 @@ class TestStatsRoundTrip:
         stats, _ = _gemm_run("staged", None)
         d = stats.to_dict()
         assert d["schema"] == STATS_SCHEMA
+        # A and B were each tiled by one split program (2 x 4 tiles)
+        assert (d["split_programs"], d["tiles_split"]) == (2, 8)
         assert RuntimeStats.from_json(stats.to_json()) == stats
 
     def test_round_trip_with_worker_fields(self):
