@@ -264,7 +264,7 @@ def run_app(name: str, executor: str = "staged", *,
     a returned stats object means the run was correct — this is what the
     report tables and the executor-comparison tests call.  For
     ``executor="sharded"`` install a mesh first (``repro.dist.use_mesh``)
-    to exercise the shard_map dispatch; without one the executor falls
+    to exercise the per-owner dispatch; without one the executor falls
     back to single-device staged dispatch and still reports home traffic.
 
     ``verify=None`` means "verify unless the executor cannot": the

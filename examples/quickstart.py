@@ -136,7 +136,7 @@ def main():
             s = rt.stats()
             total = s.cross_home_bytes + s.local_home_bytes
             print(f"[sharded] owner-computes on a {n_dev}-device mesh: "
-                  f"{s.sharded_dispatches} shard_map/vmap dispatches, "
+                  f"{s.sharded_dispatches} per-owner group programs, "
                   f"{s.cross_home_bytes / 2**20:.1f} MiB cross-home of "
                   f"{total / 2**20:.1f} MiB touched "
                   f"({100 * s.cross_home_bytes / total:.0f}% remote) "

@@ -283,7 +283,8 @@ class RuntimeConfig:
     * ``group_waves`` — staged/sharded executors: fuse identical tile
       tasks of a wavefront into one batched dispatch.
     * ``kernel_backend`` — how a grouped wave dispatches: ``"xla"`` (the
-      default vmap/shard_map path) or ``"pallas"`` (lower each eligible
+      default group program: one jitted ``vmap`` per group, one per
+      owner device on a mesh) or ``"pallas"`` (lower each eligible
       group into one fused ``pl.pallas_call`` whose grid axis is the task
       axis — ``core/wavekernel.py``, the §3.2 on-chip staging analogue).
       Ineligible groups, and groups whose kernel the TPU compiler
@@ -441,8 +442,8 @@ class RuntimeStats:
     kernel_fallback_reasons: dict[str, int] | None = None
     # sharded executor: owner-computes traffic accounting (§4.1-§4.2
     # generalized — cross-home bytes are what the DES charges contention
-    # for) plus how many grouped dispatches went through the
-    # shard_map/vmap hybrid
+    # for) plus how many group programs ran under a mesh: one per owner
+    # device per group (0 without a mesh)
     sharded_dispatches: int | None = None
     cross_home_bytes: int | None = None
     local_home_bytes: int | None = None

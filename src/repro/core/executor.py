@@ -22,7 +22,7 @@ import contextlib
 import functools
 import threading
 import time
-from collections import OrderedDict, defaultdict, deque
+from collections import OrderedDict, defaultdict
 from typing import Callable, Iterable, Protocol, Sequence, runtime_checkable
 
 import jax
@@ -296,8 +296,8 @@ class StagedExecutor(ExecutorBase):
     Firstprivate values are stacked on the host, one array per value
     position, as extra vmap operands, so index-parameterized tile tasks
     (same function, different offsets) share the dispatch too.  The
-    stacked axis is the "worker" axis; under ``shard_map`` on real
-    hardware it shards over the mesh.
+    stacked axis is the "worker" axis; on a mesh the sharded executor
+    runs one group program per owner device.
     """
 
     kind = "staged"
@@ -339,18 +339,14 @@ class StagedExecutor(ExecutorBase):
     # -- wavefront layering ---------------------------------------------------
     def _wavefronts(self, tasks: list[TaskDescriptor]) \
             -> list[list[TaskDescriptor]]:
-        mgr = getattr(self.scheduler, "_ready_mgr", None)
-        if mgr is not None:
-            return self._wavefronts_sharded(tasks, mgr)
         indeg = {td: td.deps_remaining for td in tasks}
         frontier = [td for td, d in indeg.items() if d == 0]
         waves = []
         seen = 0
         while frontier:
             # canonical intra-wave order: spawn order, not discovery
-            # order — the order is the schedule contract the sharded
-            # wave builder reproduces, so it must not depend on which
-            # predecessor happened to unlock a task first
+            # order — the schedule must not depend on which predecessor
+            # (or which dependence manager) unlocked a task first
             frontier.sort(key=lambda t: t.spawn_order)
             waves.append(frontier)
             seen += len(frontier)
@@ -362,40 +358,6 @@ class StagedExecutor(ExecutorBase):
                         if indeg[dep] == 0:
                             nxt.append(dep)
             frontier = nxt
-        if seen != len(tasks):
-            raise RuntimeError("cycle in task graph (impossible for "
-                               "footprint-derived deps)")
-        return waves
-
-    def _wavefronts_sharded(self, tasks: list[TaskDescriptor], mgr) \
-            -> list[list[TaskDescriptor]]:
-        """Wavefront layering over the sharded manager's per-home ready
-        sets: ready tasks bucket at their owner home (the same
-        owner-computes rule the per-home ready deques use), each wave is
-        the union of the buckets spawn-ordered, and the dependents
-        decrement refills next wave's buckets.  A wave is exactly the set
-        of zero-indegree tasks, so the *levels* are identical to the
-        central builder's — only who holds the ready tasks changes."""
-        indeg = {td: td.deps_remaining for td in tasks}
-        buckets = [deque() for _ in range(mgr.n_managers)]
-        for td in tasks:                 # pending order == spawn order
-            if indeg[td] == 0:
-                buckets[mgr.owner_of(td)].append(td)
-        waves = []
-        seen = 0
-        while any(buckets):
-            wave = [td for q in buckets for td in q]
-            wave.sort(key=lambda t: t.spawn_order)
-            for q in buckets:
-                q.clear()
-            waves.append(wave)
-            seen += len(wave)
-            for td in wave:
-                for dep in td.dependents:
-                    if dep in indeg:
-                        indeg[dep] -= 1
-                        if indeg[dep] == 0:
-                            buckets[mgr.owner_of(dep)].append(dep)
         if seen != len(tasks):
             raise RuntimeError("cycle in task graph (impossible for "
                                "footprint-derived deps)")
@@ -416,36 +378,15 @@ class StagedExecutor(ExecutorBase):
         return jfn
 
     @staticmethod
-    def _pulls(group: list[TaskDescriptor]) -> list:
-        """One ``(element_shape, pull(i, device))`` pair per stacked
-        operand — READS args then firstprivate values, the canonical
-        stacking order of the eagerly stacked paths (the pallas grid and
-        the sharded shard_map split; the group program keeps it too).
-        ``pull(i, device)`` produces task ``i``'s operand assembled on
-        ``device`` (left in place when None, the pallas grid)."""
-        pulls = []
-        for pos in range(len(group[0].args)):
-            if not group[0].args[pos].READS:
-                continue
-            pulls.append((
-                group[0].args[pos].region.shape,
-                lambda i, dev, p=pos:
-                    group[i].args[p].region.materialize(device=dev)))
-        for pos in range(len(group[0].values)):
-            pulls.append((
-                np.shape(group[0].values[pos]),
-                lambda i, dev, p=pos:
-                    jnp.asarray(group[i].values[p]) if dev is None
-                    else jax.device_put(jnp.asarray(group[i].values[p]),
-                                        dev)))
-        return pulls
-
-    def _stack_group(self, group: list[TaskDescriptor]) -> list:
+    def _stack_group(group: list[TaskDescriptor]) -> list:
         """Stack each READS arg across the group, then the firstprivate
         values, as eager device arrays: the operands of a fused pallas
         wave kernel, which takes them stacked."""
-        return [jnp.stack([pull(i, None) for i in range(len(group))])
-                for _, pull in self._pulls(group)]
+        ins = [jnp.stack([td.args[pos].region.materialize() for td in group])
+               for pos, arg in enumerate(group[0].args) if arg.READS]
+        ins.extend(jnp.stack([jnp.asarray(td.values[pos]) for td in group])
+                   for pos in range(len(group[0].values)))
+        return ins
 
     @staticmethod
     def _assign_outputs(td: TaskDescriptor, vals: tuple) -> None:

@@ -132,7 +132,6 @@ class TaskRuntime:
         self._exec.obs = self.obs
         self._exec.traffic = self.traffic
         self._exec.profile = self._profile = config.profile_waves
-        self._arrays: list[BlockArray] = []
         # ``repro.serve`` attaches its AdmissionController here so
         # ``stats()`` surfaces the admission_* fields; None when the
         # runtime is not serving
@@ -192,7 +191,6 @@ class TaskRuntime:
             store = make_store(ba)
             if store is not None:
                 ba.use_store(store)
-        self._arrays.append(ba)
         return ba
 
     def from_array(self, arr, block_shape: Sequence[int],

@@ -16,27 +16,25 @@ benchmark tables can show what a placement policy saves.
 Residency: blocks are *device-resident*.  :meth:`ShardedExecutor.make_store`
 hands every registered ``BlockArray`` a
 :class:`~repro.core.blocks.DeviceTileStore`, so each tile physically lives
-on the device serving its home.  A grouped wave dispatch assembles every
-device's operand shard *on that device* (``Region.materialize(device=...)``
-inside :meth:`_sharded_stack`): tiles a task owns never move, a cross-home
-read transfers exactly once, and nothing routes through a staging device —
-``RuntimeStats.bytes_staged`` stays zero, and ``tile_moves``/``bytes_moved``
-report the transfers that actually happened (measured at the memory layer
-by :class:`~repro.core.blocks.TileTraffic`, not estimated from footprints).
-Results come back shard-by-shard (:meth:`_store_sharded` reads each task's
-output from the shard data on its executing device) and commit tile-by-tile
-to the output's home.
+on the device serving its home.  A dispatch assembles its operands *on the
+owner device* (``Region.materialize(device=...)``): tiles a task owns never
+move, a cross-home read transfers exactly once, and nothing routes through
+a staging device — ``RuntimeStats.bytes_staged`` stays zero, and
+``tile_moves``/``bytes_moved`` report the transfers that actually happened
+(measured at the memory layer by :class:`~repro.core.blocks.TileTraffic`,
+not estimated from footprints); without owner overrides they equal
+``cross_home_bytes``.  Results commit tile-by-tile to the output's home:
+a no-op when owner-computes held.
 
-Dispatch reuses the staged executor's wavefront grouping unchanged: tasks
-of one wavefront with the same function and footprint/value structure
-stack into one batched call.  With a mesh context active
-(:func:`repro.dist.use_mesh`) that call becomes a shard_map/vmap hybrid —
-the stacked task axis is sharded over every mesh axis (tasks sorted by
-owner so each device's slice is, under block-cyclic homes, the tasks it
-owns) and ``vmap`` maps the per-device slice.  Groups a mesh cannot split
-evenly fall back to per-owner-device sub-dispatches, and with no mesh at
-all every dispatch degrades to the plain staged path on the default
-device — the single-device fallback tests and CI run.
+Dispatch reuses the staged executor's wavefront grouping and its group
+program unchanged: tasks of one wavefront with the same function and
+footprint/value structure form a group.  With a mesh context active
+(:func:`repro.dist.use_mesh`) the group splits by owner device, and each
+device's share runs as one group program pinned there
+(:meth:`~repro.core.executor.StagedExecutor._group_call` with a
+``device``), whatever the wave's width or its owners' balance.  With no
+mesh at all every dispatch degrades to the plain staged path on the
+default device — the single-device fallback tests and CI run.
 
 When ``RuntimeConfig.owner_skew_threshold`` is set, a wave group whose
 owner loads are badly skewed is rebalanced before dispatch
@@ -47,10 +45,8 @@ against one counted copy, the override the paper's Fig 4 numbers argue for.
 """
 from __future__ import annotations
 
-import functools
 from collections import defaultdict
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -58,7 +54,7 @@ from repro.obs.profiler import trace_span
 
 from .blocks import DeviceTileStore
 from .executor import StagedExecutor
-from .graph import TaskDescriptor, TaskState, normalize_outputs
+from .graph import TaskDescriptor
 from .placement import device_assignment, rebalance_owners
 
 __all__ = ["ShardedExecutor", "owner_home"]
@@ -86,8 +82,7 @@ class ShardedExecutor(StagedExecutor):
                          kernel_backend=kernel_backend)
         self.n_homes = n_homes
         self.owner_skew_threshold = owner_skew_threshold
-        self._smap: dict = {}           # (fn, mesh, n_ins) -> jitted hybrid
-        self.sharded_dispatches = 0
+        self.sharded_dispatches = 0    # group programs under a mesh
         self.cross_home_bytes = 0
         self.local_home_bytes = 0
         self.owner_overrides = 0
@@ -183,10 +178,10 @@ class ShardedExecutor(StagedExecutor):
                 self._place(group)
             return super()._run_group(group)
         if self.kernel_backend == "pallas":
-            # under a live mesh the group dispatches through the
-            # shard_map/vmap hybrid; a fused pallas grid would pin the
-            # whole wave to one device and undo owner-computes, so the
-            # mesh path is a named fallback, not a lowering attempt
+            # under a live mesh the group runs as one group program per
+            # owner device; a fused pallas grid would pin the whole wave
+            # to one device and undo owner-computes, so the mesh path is
+            # a named fallback, not a lowering attempt
             self._note_kernel_fallback(group, "sharded_mesh")
         with trace_span(self._stack_span, self.profile):
             calls = self._mesh_calls(group, self._place(group), ctx)
@@ -195,96 +190,24 @@ class ShardedExecutor(StagedExecutor):
     def _mesh_calls(self, group: list[TaskDescriptor], owners: list[int],
                     ctx) -> list[tuple]:
         """Assemble the group's operands on the mesh: one ``(fn, operands,
-        store)`` per dispatch (the staged contract of ``_calls``)."""
-        mesh = ctx.mesh
+        store)`` per dispatch (the staged contract of ``_calls``), each on
+        its tasks' owner device."""
         devmap = device_assignment(self.n_homes, ctx)
-        ndev = int(np.asarray(mesh.devices).size)
-        if len(group) == 1 or not self.group:
+        if not self.group:
             jfn = self._jitted(group[0].fn)
             return [self._task_call(td, jfn, device=devmap[h % len(devmap)])
                     for td, h in zip(group, owners)]
-        # sort by owner device so the sharded task axis hands each device
-        # (under balanced block-cyclic homes) exactly the tasks it owns
-        order = sorted(range(len(group)), key=lambda i: owners[i] % ndev)
-        group = [group[i] for i in order]
-        owners = [owners[i] for i in order]
-        if len(group) % ndev == 0:
-            return [self._sharded_call(group, mesh)]
-        # a wave the mesh cannot split evenly: owner-computes
-        # sub-dispatches, one batched call per owner device
         by_dev = defaultdict(list)
         for td, h in zip(group, owners):
             by_dev[devmap[h % len(devmap)]].append(td)
         return [self._subgroup_call(sub, dev) for dev, sub in by_dev.items()]
 
-    def _sharded_stack(self, group: list[TaskDescriptor],
-                       sharding) -> tuple[list, list]:
-        """Assemble each stacked operand (READS args then firstprivate
-        values, the staged stacking order) directly as a sharded global
-        array: every device's shard is built *on that device* by
-        destination-aware assembly — tiles resident there are read in
-        place, a cross-home tile transfers once, and no operand ever
-        routes through a staging device.  Returns ``(ins, slices)`` where
-        ``slices`` is the per-device ``(device, lo, hi)`` split of the
-        task axis (``_store_sharded`` reads results back along it)."""
-        n = len(group)
-        slices = [(dev, *idx[0].indices(n)[:2])
-                  for dev, idx in sharding.devices_indices_map((n,)).items()]
-        ins = []
-        for elt_shape, pull in self._pulls(group):
-            shards = [jnp.stack([pull(i, dev) for i in range(lo, hi)])
-                      for dev, lo, hi in slices]
-            ins.append(jax.make_array_from_single_device_arrays(
-                (n, *elt_shape), sharding, shards))
-        return ins, slices
-
-    def _store_sharded(self, group: list[TaskDescriptor], result,
-                       slices: list) -> None:
-        """Unstack a sharded result without cross-device gathers: each
-        output's per-device shard holds exactly the tasks that ran there,
-        so every task's value is read from the shard data already on its
-        executing device and committed tile-by-tile to its output's home
-        (a no-op when owner-computes held; one counted transfer when the
-        owner override spilled the task)."""
-        result = normalize_outputs(result, len(group[0].outputs),
-                                   group[0].name or group[0].tid)
-        self.grouped_dispatches += 1
-        shard_data = [{s.device: s.data for s in out.addressable_shards}
-                      for out in result]
-        for dev, lo, hi in slices:
-            for i in range(lo, hi):
-                self._assign_outputs(
-                    group[i],
-                    tuple(data[dev][i - lo] for data in shard_data))
-
-    def _sharded_call(self, group: list[TaskDescriptor], mesh) -> tuple:
-        """The shard_map/vmap hybrid: stacked operands are sharded along
-        the task axis over every mesh axis; inside each shard ``vmap``
-        maps the local slice."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        fn = group[0].fn
-        for td in group:
-            td.state = TaskState.RUNNING
-        spec = P(tuple(mesh.axis_names))
-        ins, slices = self._sharded_stack(group, NamedSharding(mesh, spec))
-        key = (fn, mesh, len(ins))
-        sfn = self._smap.get(key)
-        if sfn is None:
-            sfn = self._smap[key] = jax.jit(jax.shard_map(
-                jax.vmap(fn), mesh=mesh,
-                in_specs=tuple(spec for _ in ins), out_specs=spec,
-                check_vma=False))
-        self._last_mode = "shard_map"
-        self.sharded_dispatches += 1
-        return sfn, ins, functools.partial(self._store_sharded, group,
-                                           slices=slices)
-
     def _subgroup_call(self, group: list[TaskDescriptor], dev) -> tuple:
-        """The group program pinned to one owner device (the uneven-wave
-        fallback; computation follows the placed operands)."""
+        """The group program pinned to one owner device (computation
+        follows the placed operands)."""
         if len(group) == 1:
             return self._task_call(group[0], self._jitted(group[0].fn),
                                    device=dev)
         self._last_mode = "vmap_device"
+        self.sharded_dispatches += 1
         return self._group_call(group, device=dev)

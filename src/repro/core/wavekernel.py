@@ -14,7 +14,7 @@ back through the output ``BlockSpec``s — tile loads/stores happen in
 on-chip memory instead of one HBM round trip per vmap lane.
 
 Selection is ``RuntimeConfig.kernel_backend``: ``"xla"`` (the default) is
-today's vmap/shard_map dispatch, ``"pallas"`` tries this lowering per
+the group program (``vmap`` in one jit), ``"pallas"`` tries this lowering per
 group and *automatically falls back* to the XLA path for groups it cannot
 take — :func:`eligibility` names the structural reasons (single-task
 group, non-rectangular footprint, mixed dtypes, grid overflow, per-step

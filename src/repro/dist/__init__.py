@@ -41,7 +41,7 @@ def single_device_mesh(axis: str = "data"):
     """A one-device mesh over the default local device.
 
     This is the smallest mesh the mesh-dependent code paths accept — the
-    :class:`~repro.core.sharded.ShardedExecutor`'s shard_map dispatch, the
+    :class:`~repro.core.sharded.ShardedExecutor`'s per-owner dispatch, the
     sharding rules, ``placement.device_assignment`` — so single-device
     tests and CI exercise the *same* code the real mesh runs, not a
     separate branch.  Install it with :func:`use_mesh`.

@@ -25,8 +25,8 @@ Kinds:
   (``TileTraffic`` snapshots around the wave, so per-wave
   ``bytes_moved``/``bytes_staged`` sum exactly to ``RuntimeStats``).
 * ``dispatch``       — one batched (or single) dispatch: function name,
-  task count, dispatch mode (``jit``/``vmap``/``shard_map``/
-  ``vmap_device``/``pallas``) and its wall time.
+  task count, dispatch mode (``jit``/``vmap``/``vmap_device``/
+  ``pallas``) and its wall time.
 * ``kernel_dispatch``— the wave-kernel backend decided how one group
   dispatches (emitted only under ``kernel_backend="pallas"``):
   ``backend`` is ``"pallas"`` (fused grid) or ``"xla"`` (fallback), and

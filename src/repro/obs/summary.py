@@ -31,7 +31,7 @@ def mode_latency(events: list[Event]) -> dict[str, dict]:
     ``mode -> {count, total_s, p50_s, p99_s}``, modes sorted by name.
 
     This is the before/after axis for dispatch-path work (e.g. jit vs
-    vmap vs shard_map): the same trace answers "where did the wall time
+    vmap vs vmap_device): the same trace answers "where did the wall time
     go" per mode, with tail latency (p99) next to the median."""
     by_mode: dict[str, list[float]] = {}
     for e in events:

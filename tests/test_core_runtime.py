@@ -345,15 +345,12 @@ def _group_program_probe(executor: str) -> dict:
     """Run ``cholesky_app`` (n=64, tile=16: 20 tasks) once to trace every
     group shape, then again with eager ``jnp.stack`` and
     ``jax.Array.__getitem__`` patched to raise inside the barrier.  On a
-    mesh the even shard_map split (``ShardedExecutor._sharded_call``)
-    still stacks and reads its shards eagerly, and is exempt."""
-    import contextlib
-    import functools
+    mesh every group runs as per-owner group programs, with no
+    exemption."""
     from unittest import mock
 
     import jax
     from benchmarks.apps import cholesky_app
-    from repro.core.sharded import ShardedExecutor
 
     want = np.asarray(cholesky_app(TaskRuntime(executor="sequential"),
                                    n=64, tile=16, verify=False).gather())
@@ -371,45 +368,23 @@ def _group_program_probe(executor: str) -> dict:
     for fn in ex._vjit:
         ex._vjit[fn] = counted(ex._vjit[fn])
 
-    exempt = [False]
-
-    def exempting(method):
-        @functools.wraps(method)
-        def call(*args, **kwargs):
-            exempt[0] = True
-            try:
-                return method(*args, **kwargs)
-            finally:
-                exempt[0] = False
-        return call
-
     real_stack = jnp.stack
     array_type = type(jnp.zeros(1))
-    real_getitem = array_type.__getitem__
 
     def stack(arrays, *args, **kwargs):
         arrays = list(arrays)
-        if not exempt[0] and not all(isinstance(a, jax.core.Tracer)
-                                     for a in arrays):
+        if not all(isinstance(a, jax.core.Tracer) for a in arrays):
             raise AssertionError("eager jnp.stack in the barrier")
         return real_stack(arrays, *args, **kwargs)
 
     def getitem(self, idx):
-        if not exempt[0]:
-            raise AssertionError("eager per-task slice in the barrier")
-        return real_getitem(self, idx)
+        raise AssertionError("eager per-task slice in the barrier")
 
     barrier = ex.barrier
 
     def patched_barrier():
-        with contextlib.ExitStack() as patches:
-            patches.enter_context(mock.patch.object(jnp, "stack", stack))
-            patches.enter_context(
-                mock.patch.object(array_type, "__getitem__", getitem))
-            for name in ("_sharded_stack", "_store_sharded"):
-                patches.enter_context(mock.patch.object(
-                    ShardedExecutor, name,
-                    exempting(getattr(ShardedExecutor, name))))
+        with mock.patch.object(jnp, "stack", stack), \
+                mock.patch.object(array_type, "__getitem__", getitem):
             barrier()
 
     ex.barrier = patched_barrier
@@ -421,7 +396,8 @@ def _group_program_probe(executor: str) -> dict:
             "group_program_tasks": (after.group_program_tasks
                                     - before.group_program_tasks),
             "program_widths": widths,
-            "sharded_dispatches": after.sharded_dispatches or 0}
+            "sharded_dispatches": ((after.sharded_dispatches or 0)
+                                   - (before.sharded_dispatches or 0))}
 
 
 @functools.lru_cache(maxsize=None)
@@ -456,8 +432,8 @@ def test_group_program_runs_no_eager_stack_or_slice(executor):
     run = _group_program_run(executor)
     assert run["program_widths"]
     if executor == "sharded":
-        # both mesh paths ran: the even split and per-owner group programs
-        assert run["sharded_dispatches"] > 0
+        # every group program ran pinned to an owner device
+        assert run["sharded_dispatches"] == len(run["program_widths"]) > 0
 
 
 @pytest.mark.parametrize("executor", ["staged", "sharded"])

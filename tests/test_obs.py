@@ -543,7 +543,7 @@ def _record_spans(executor: str, profile: bool) -> dict:
                          n_controllers=2)
         cholesky_app(rt, n=64, tile=16)
         ex = rt._exec
-        for cache in (ex._jit, ex._vjit, getattr(ex, "_smap", {})):
+        for cache in (ex._jit, ex._vjit):
             for key in cache:
                 cache[key] = counted(cache[key])
         before = rt.stats()

@@ -213,15 +213,15 @@ class TestResidencyStats:
         assert s.bytes_staged == 0
 
     def test_mesh_wave_dispatch_never_stages(self):
-        """The acceptance criterion on the single-device mesh: grouped
-        wave dispatches stage zero operand bytes through a non-home
-        device."""
+        """The acceptance criterion on the single-device mesh: every
+        group runs as a program pinned to its owner device and stages
+        zero operand bytes through a non-home device."""
         rng = np.random.default_rng(10)
         a = rng.standard_normal((128, 128), dtype=np.float32)
         with dist.use_mesh(dist.single_device_mesh()):
             rt = TaskRuntime(executor="sharded", placement="striped")
             _, s = _gemm_program(rt, a, a)
-        assert s.sharded_dispatches > 0
+        assert s.sharded_dispatches == s.grouped_dispatches > 0
         assert s.bytes_staged == 0
 
 
@@ -282,8 +282,8 @@ for ba in arrays:
     for idx in ba.block_indices():
         assert ba.tile_device(idx) == devmap[ba.home[idx] % 2], \
             (ba.name, idx)
-# every wave went through the shard_map hybrid
-assert s.sharded_dispatches == 4, s.sharded_dispatches
+# every wave ran one group program per owner device: 4 waves x 2
+assert s.sharded_dispatches == s.grouped_dispatches == 8, s
 # (b) zero staging; measured moves equal the footprint prediction
 assert s.bytes_staged == 0, s.bytes_staged
 assert s.bytes_moved == s.cross_home_bytes, (s.bytes_moved,
