@@ -289,8 +289,9 @@ class StagedExecutor(ExecutorBase):
     input/output signature run as one device program per group — the TPU
     analogue of handing each worker its MPB queue of identical tile tasks.
     The group program (:meth:`_group_program`, one jit per body, traced
-    per group shape) takes the group's operand tiles as they are, stacks
-    them, applies ``vmap(fn)`` and hands each output back as a tuple of
+    per group shape and read pattern) takes each distinct operand tile
+    once with a static index of who reads it, stacks them per position,
+    applies ``vmap(fn)`` and hands each output back as a tuple of
     per-task values: the stack and the per-task slices run inside the one
     dispatch, not as host-dispatched operations of their own.
     Firstprivate values are stacked on the host, one array per value
@@ -317,8 +318,8 @@ class StagedExecutor(ExecutorBase):
         self.waves_run = 0
         self.grouped_dispatches = 0
         self.group_program_tasks = 0   # tasks run through a group program
-        self.group_operand_tiles = 0   # READS operands passed to them
-        self.group_distinct_tiles = 0  # distinct operands, per group
+        self.group_operand_tiles = 0   # READS operands, task x position
+        self.group_distinct_tiles = 0  # tiles handed to them (distinct)
         self.kernel_dispatches = 0     # groups fused into one pallas grid
         self.kernel_fallbacks = 0      # pallas-requested groups gone XLA
         self.kernel_fallback_reasons: dict[str, int] = defaultdict(int)
@@ -412,49 +413,77 @@ class StagedExecutor(ExecutorBase):
 
     def _group_program(self, fn: Callable) -> Callable:
         """The one device program that runs a group of ``fn`` tasks:
-        ``program(reads, values)`` takes per READS position the group's
-        tiles in task order and per firstprivate position the values
-        stacked on the host, stacks the tiles, applies ``vmap(fn)`` and
-        returns each output as a tuple of per-task values.  It carries
+        ``program(tiles, values, index)`` takes the group's distinct
+        READS tiles once each, per firstprivate position the values
+        stacked on the host, and the static ``index``: per READS
+        position, each task's slot in ``tiles``.  It stacks
+        ``[tiles[s] for s in slots]`` per position (a tile read by
+        several tasks or positions feeds several stack slots), applies
+        ``vmap(fn)`` and returns each output as a tuple of per-task
+        values.  ``index`` is part of the jit key: groups whose tasks
+        share reads in the same pattern share one trace.  It carries
         ``fn``'s name, so the device trace names it ``jit_<fn>``.  The
         values enter strongly typed in their canonical dtype."""
         program = self._vjit.get(fn)
         if program is None:
             vfn = jax.vmap(fn)
 
-            @functools.wraps(fn)
-            def run(reads, values):
-                out = vfn(*(jnp.stack(tiles) for tiles in reads), *values)
+            def run(tiles, values, index):
+                out = vfn(*(jnp.stack([tiles[s] for s in slots])
+                            for slots in index), *values)
                 return jax.tree.map(lambda x: tuple(jnp.unstack(x)), out)
 
-            program = self._vjit[fn] = jax.jit(run)
+            # the name, not functools.wraps: jit would read fn's
+            # signature through __wrapped__ and reject the static index
+            run.__name__ = run.__qualname__ = fn.__name__
+            program = self._vjit[fn] = jax.jit(run, static_argnums=2)
         return program
 
     def _group_call(self, group: list[TaskDescriptor],
                     device=None) -> tuple:
         """The whole group as one dispatch of its group program:
-        ``(program, (reads, values), store)``.  ``device`` (if given) is
-        the execution destination: each tile is assembled *directly on
-        it* (``Region.materialize(device=...)``), so tiles resident
-        elsewhere move exactly once, and the jit follows its committed
-        inputs there; the plain staged path leaves tiles where they are.
-        Each firstprivate position is one host-stacked array, one
-        transfer per group."""
+        ``(program, (tiles, values, index), store)``.  Each distinct
+        READS region (by array and tile range) is assembled once and
+        passed once, whichever tasks and positions read it; ``index``
+        numbers the slots in order of first appearance, positions in
+        argument order and tasks in group order, so it follows from
+        spawn order alone.  A group with no repeated region gets the
+        identity index: every tile once, position-major, in task order.
+        Only read-read sharing merges: within a wave no tile is both
+        written and read.  ``device`` (if given) is the execution destination: each
+        distinct tile is assembled *directly on it*
+        (``Region.materialize(device=...)``), so a tile resident
+        elsewhere moves once per group, however many of its tasks read
+        it, and the jit follows its committed inputs there; the plain
+        staged path leaves tiles where they are.  Each firstprivate
+        position is one host-stacked array, one transfer per group."""
         for td in group:
             td.state = TaskState.RUNNING
-        regions = [[td.args[pos].region for td in group]
-                   for pos, arg in enumerate(group[0].args) if arg.READS]
-        reads = tuple(tuple(r.materialize(device=device) for r in rs)
-                      for rs in regions)
+        slots: dict = {}
+        tiles = []
+        index = []
+        for pos, arg in enumerate(group[0].args):
+            if not arg.READS:
+                continue
+            row = []
+            for td in group:
+                r = td.args[pos].region
+                key = (r.array.array_id, r.ranges)
+                slot = slots.get(key)
+                if slot is None:
+                    slot = slots[key] = len(tiles)
+                    tiles.append(r.materialize(device=device))
+                row.append(slot)
+            index.append(tuple(row))
         values = tuple(np.stack([td.values[pos] for td in group])
                        for pos in range(len(group[0].values)))
         if device is not None:
             values = jax.device_put(values, device)
         self.group_program_tasks += len(group)
-        self.group_operand_tiles += len(group) * len(regions)
-        self.group_distinct_tiles += len({(r.array.array_id, r.ranges)
-                                          for rs in regions for r in rs})
-        return (self._group_program(group[0].fn), (reads, values),
+        self.group_operand_tiles += len(group) * len(index)
+        self.group_distinct_tiles += len(tiles)
+        return (self._group_program(group[0].fn),
+                (tuple(tiles), values, tuple(index)),
                 functools.partial(self._store_group, group))
 
     def _task_call(self, td: TaskDescriptor, jfn: Callable,

@@ -18,13 +18,15 @@ hands every registered ``BlockArray`` a
 :class:`~repro.core.blocks.DeviceTileStore`, so each tile physically lives
 on the device serving its home.  A dispatch assembles its operands *on the
 owner device* (``Region.materialize(device=...)``): tiles a task owns never
-move, a cross-home read transfers exactly once, and nothing routes through
-a staging device — ``RuntimeStats.bytes_staged`` stays zero, and
+move, a cross-home tile moves once per owner device and group program
+(however many of its tasks read it), and nothing routes through a staging
+device — ``RuntimeStats.bytes_staged`` stays zero, and
 ``tile_moves``/``bytes_moved`` report the transfers that actually happened
 (measured at the memory layer by :class:`~repro.core.blocks.TileTraffic`,
-not estimated from footprints); without owner overrides they equal
-``cross_home_bytes``.  Results commit tile-by-tile to the output's home:
-a no-op when owner-computes held.
+not estimated from footprints).  ``cross_home_bytes`` charges every task's
+cross-home reads, so without owner overrides the measured bytes sit below
+it wherever tasks on one device share a read.  Results commit
+tile-by-tile to the output's home: a no-op when owner-computes held.
 
 Dispatch reuses the staged executor's wavefront grouping and its group
 program unchanged: tasks of one wavefront with the same function and

@@ -360,9 +360,9 @@ def _group_program_probe(executor: str) -> dict:
     widths = []
 
     def counted(program):
-        def call(reads, values):
-            widths.append(len(reads[0]))
-            return program(reads, values)
+        def call(tiles, values, index):
+            widths.append(len(index[0]))
+            return program(tiles, values, index)
         return call
 
     for fn in ex._vjit:

@@ -405,7 +405,9 @@ out["potrf"] = {"bit_identical": bool(np.array_equal(want, got)),
                 "bytes_moved": moved,
                 "cross_home_bytes": s.cross_home_bytes,
                 "bytes_staged": s.bytes_staged,
-                "tile_bytes": tile * tile * 4}
+                "tile_bytes": tile * tile * 4,
+                "group_programs": sum(p._cache_size()
+                                      for p in rt._exec._vjit.values())}
 print(json.dumps(out))
 """
 
@@ -440,11 +442,20 @@ def test_sharded_on_four_devices_matches_sequential():
 def test_four_device_cholesky_moves_only_cross_home_tiles():
     """Owner-computes on the benchmark's Cholesky DAG (``chipbench``'s
     potrf program, grid 16, striped homes over 4 devices): every task
-    runs on its owner device, so the measured movement is exactly the
-    1112 cross-home tiles the footprints predict, and the factor is
-    bit-identical to sequential."""
+    runs on its owner device and each group program receives a distinct
+    tile once, so a cross-home tile moves once per owner device and
+    group: 319 moves, against the 1112 cross-home tile reads the
+    footprints count.  The factor is bit-identical to sequential."""
     run = _four_device_run()["potrf"]
     assert run["bit_identical"]
-    assert run["bytes_moved"] == run["cross_home_bytes"]
-    assert run["bytes_moved"] == 1112 * run["tile_bytes"]
+    assert run["cross_home_bytes"] == 1112 * run["tile_bytes"]
+    assert run["bytes_moved"] == 319 * run["tile_bytes"]
+    assert run["bytes_moved"] < run["cross_home_bytes"]
     assert run["bytes_staged"] == 0
+
+
+def test_four_device_cholesky_group_programs_per_shape():
+    """The static read index of the per-owner group programs adds no
+    program: the grid-16 Cholesky on 4 devices holds one per grouped
+    shape, 28, as the staged executor does."""
+    assert _four_device_run()["potrf"]["group_programs"] == 28
